@@ -98,6 +98,8 @@ def main(argv=None) -> int:
     if args.rss_cap_mb:
         from benchmarks.scale_sweep import apply_rss_cap
         apply_rss_cap(args.rss_cap_mb)
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
 
     t0 = time.time()
     summary = run_fuzz_job(

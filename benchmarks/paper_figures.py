@@ -16,13 +16,18 @@ from repro.core.qos import interference_report, regions_isolated
 from repro.serving.pool import BankedKVPool
 
 
+def fig4_point(X: int, num_txns: int):
+    """(trace, params) of one Fig. 4 point: ``X`` full-duplex masters at
+    full injection on the prototype geometry."""
+    tr = random_uniform(X, num_txns, burst=16, full_duplex=True)
+    return tr, SimParams(max_cycles=int(num_txns * 16 * 1.3) + 2000)
+
+
 def fig4_throughput(*, num_txns: int = 300, counts=(1, 2, 4, 8, 16)) -> Dict:
     """Read/write throughput + latency vs number of parallel masters."""
     rows = {}
     for X in counts:
-        tr = random_uniform(X, num_txns, burst=16, full_duplex=True)
-        need = int(num_txns * 16 * 1.3) + 2000
-        m = simulate(tr, SimParams(max_cycles=need))
+        m = simulate(*fig4_point(X, num_txns))
         rows[X] = {
             "read_throughput": float(m["read_throughput"][:X].mean()),
             "write_throughput": float(m["write_throughput"][X:].mean()),

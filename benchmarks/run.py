@@ -119,6 +119,9 @@ def main() -> None:
                 f"valid jobs: {valid} (see also --list)")
         jobs = [j for j in jobs if j[0] in wanted]
 
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
+
     results = {}
     failed = []
     print("name,compile_s,run_s,derived")
@@ -156,16 +159,6 @@ def main() -> None:
         key = next(iter(out))
         cs = "" if compile_s is None else f"{compile_s:.2f}"
         print(f"{name},{cs},{run_s:.2f},{json.dumps(out[key])[:110]}")
-
-    # roofline table (from the dry-run artifacts, if present)
-    try:
-        from benchmarks.roofline import interesting_cells, table
-        tbl = table()
-        results["roofline"] = {"table": tbl,
-                               "picks": interesting_cells()}
-        print(f"roofline,0.0,{len(tbl.splitlines()) - 1} cells")
-    except Exception as e:  # dry-run artifacts absent
-        print(f"roofline,0.0,skipped ({e})")
 
     out_path = Path("experiments/bench_results.json")
     out_path.parent.mkdir(exist_ok=True)

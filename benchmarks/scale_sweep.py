@@ -89,21 +89,31 @@ def apply_rss_cap(mb: int) -> None:
     resource.setrlimit(resource.RLIMIT_AS, (mb * 2**20, mb * 2**20))
 
 
+def scale_grid(*, points: int, masters: int = 2, txns: int = 8,
+               max_cycles: int = 48, seed: int = 0):
+    """(shared schedule, ``points`` SimParams): the grid :func:`scale_sweep`
+    runs, on the schedule pipeline with streaming collection."""
+    from repro.core.simulator import SCHEDULE_PIPELINE, SimParams
+
+    compiled = _tiny_scenario(masters=masters, txns=txns, seed=seed)
+    base = SimParams(geom=compiled.scenario.geom, max_cycles=max_cycles,
+                     stages=SCHEDULE_PIPELINE, collect="stream")
+    return compiled.schedule(), _grid(base, points)
+
+
 def scale_sweep(*, points: int = 10_000, chunk: int = 512,
                 masters: int = 2, txns: int = 8, max_cycles: int = 48,
                 seed: int = 0) -> Dict:
     """Run a ``points``-sized dyn-parameter grid chunked over ONE schedule."""
     from repro.core.percentile import STREAM_PCTS, p2_merge_quantile
-    from repro.core.simulator import (SCHEDULE_PIPELINE, STREAM_CLASSES,
-                                      SimParams, carry_nbytes, input_nbytes,
+    from repro.core.simulator import (STREAM_CLASSES, batch_envelope,
+                                      carry_nbytes, input_nbytes,
                                       simulate_batch)
     from repro.scenarios import QOS_CLASSES
 
-    compiled = _tiny_scenario(masters=masters, txns=txns, seed=seed)
-    sched = compiled.schedule()
-    base = SimParams(geom=compiled.scenario.geom, max_cycles=max_cycles,
-                     stages=SCHEDULE_PIPELINE, collect="stream")
-    prms = _grid(base, points)
+    sched, prms = scale_grid(points=points, masters=masters, txns=txns,
+                             max_cycles=max_cycles, seed=seed)
+    base = batch_envelope(prms)
 
     t0 = time.perf_counter()
     out = simulate_batch([sched], prms, chunk=chunk)
@@ -152,6 +162,8 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
     args = ap.parse_args(argv)
     if args.rss_cap_mb:
         apply_rss_cap(args.rss_cap_mb)
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     summary = scale_sweep(points=args.points, chunk=args.chunk,
                           max_cycles=args.max_cycles)
     text = json.dumps(summary, indent=1)

@@ -307,6 +307,8 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
                          "beat the fixed horizon by this wall-clock factor "
                          "(0 disables; default 1.5)")
     args = ap.parse_args(argv)
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     summary = (serving_scale(num_requests=args.requests,
                              speedup_floor=args.speedup_floor)
                if args.scale else serving_cosim())

@@ -63,8 +63,8 @@ configuration instead of by editing ``cycle()``:
 The per-bank comparator tree itself is a swappable backend
 (``SimParams.arbiter``): ``"jax"`` runs the two-pass ``segment_min``
 reference, ``"pallas"`` the Pallas TPU kernel
-(``kernels/bank_arbiter/``, ``interpret=True`` CPU fallback) — bit-exact
-either way (hypothesis-tested grant-for-grant).
+(``kernels/bank_arbiter/``; compiled on TPU, interpreted on CPU) —
+bit-exact either way (hypothesis-tested grant-for-grant).
 
 Everything is a fixed-size jnp array and one ``lax.scan`` over cycles, so a
 whole sweep runs as a single vmapped scan: :func:`simulate_batch` evaluates a
@@ -75,7 +75,6 @@ dataflow (outstanding credits, buffer depth, pipeline latencies, bank
 occupancy, hop latency, ingress credits) are passed as a traced ``dyn`` vector
 so they can differ per point; parameters that shape the program (geometry,
 banking, burst ceiling, cycle count, pipeline, arbiter backend) stay static.
-Off-accelerator the jitted cores donate their input buffers.
 
 Traces may carry per-transaction earliest-issue times (``Trace.start``), which
 gates command acceptance — this is how the scenario engine expresses injection
@@ -465,8 +464,9 @@ def compile_simulate(trace, prm: SimParams):
     compile+execute call — e.g. the early-exit ON/OFF wall-clock gate,
     where one fixed-horizon execution is expensive enough that running it
     twice just to warm the jit cache would dominate the job.  The runner
-    holds its prepared device inputs, so treat it as single-use on
-    backends where the cores donate their input buffers (not CPU).
+    keeps its prepared device inputs (the cores donate nothing), so it can
+    be called any number of times.  ``run.compiled`` is the compiled
+    program, for callers that inspect it (``as_text()``).
     """
     use_sched = prm.uses_schedule()
     t = _as_input(trace, use_sched)
@@ -479,6 +479,7 @@ def compile_simulate(trace, prm: SimParams):
         out = jax.block_until_ready(compiled(*args))
         return jax.tree_util.tree_map(np.asarray, out)
 
+    run.compiled = compiled
     return run
 
 
@@ -502,17 +503,20 @@ def batch_envelope(prms: Sequence[SimParams]) -> SimParams:
                                inflight_override=inflight)
 
 
-def batch_sharding(batch_size: int):
-    """``NamedSharding`` that splits the batch axis across every visible
-    device, or ``None`` when sharding cannot help (a single device, or a
-    batch the device count does not divide) — the graceful fallback path.
-    """
+def batch_sharding(batch_size: int, axis: int = 0):
+    """``NamedSharding`` that splits batch ``axis`` across every visible
+    device, or ``None`` on a single device.  With several devices the batch
+    must be a device multiple (:func:`simulate_batch` pads up to one);
+    anything else raises rather than leaving the grid on one device."""
     devices = jax.devices()
-    if len(devices) <= 1 or batch_size % len(devices) != 0:
+    if len(devices) == 1:
         return None
+    if batch_size % len(devices):
+        raise ValueError(f"batch of {batch_size} does not split across "
+                         f"{len(devices)} devices; pad it to a multiple")
     mesh = jax.sharding.Mesh(np.array(devices), ("batch",))
-    return jax.sharding.NamedSharding(mesh,
-                                      jax.sharding.PartitionSpec("batch"))
+    return jax.sharding.NamedSharding(
+        mesh, jax.sharding.PartitionSpec(*([None] * axis), "batch"))
 
 
 def _pad_batch(arrs: list, pad: int) -> list:
@@ -521,6 +525,24 @@ def _pad_batch(arrs: list, pad: int) -> list:
     if pad == 0:
         return arrs
     return [np.concatenate([a, np.repeat(a[-1:], pad, axis=0)]) for a in arrs]
+
+
+@dataclass(frozen=True)
+class PreparedBatch:
+    """What :func:`simulate_batch` runs: the compiled program and its placed
+    device inputs.  ``args[batched:]`` carry the (padded) batch on their
+    leading ``lead`` axes."""
+    fn: Callable
+    args: tuple
+    size: int          # the caller's point count B
+    lead: int          # leading batch axes: 1, or 2 (chunks, points) chunked
+    batched: int       # index of the first batched input
+
+    def run(self) -> Dict[str, np.ndarray]:
+        out = self.fn(*self.args)
+        return jax.tree_util.tree_map(
+            lambda a: np.asarray(a).reshape((-1,) + a.shape[self.lead:])
+            [:self.size], out)
 
 
 def simulate_batch(traces, prms: Sequence[SimParams], *,
@@ -547,11 +569,18 @@ def simulate_batch(traces, prms: Sequence[SimParams], *,
       back to B.  Combine with ``collect="stream"`` points to keep the
       *outputs* fixed-size too.
     * **Sharding** (``shard=True``, default) — with more than one JAX
-      device, the batch axis is sharded via :func:`batch_sharding`;
-      non-divisible batches are padded up to the device multiple (and
-      sliced back) instead of falling back to one device.  In chunked mode
-      the per-chunk axis is sharded when C divides the device count.
+      device, the batch axis (each chunk's point axis when chunked) is
+      split across every device via :func:`batch_sharding`.  A batch or
+      chunk the device count does not divide is padded up to the device
+      multiple and sliced back, on every path.
     """
+    return prepare_batch(traces, prms, shard=shard, chunk=chunk).run()
+
+
+def prepare_batch(traces, prms: Sequence[SimParams], *, shard: bool = True,
+                  chunk: Optional[int] = None) -> PreparedBatch:
+    """Validate, pad, place and pick the program for :func:`simulate_batch`
+    (same arguments) without running it."""
     if not prms:
         raise ValueError("empty parameter batch")
     B = len(prms)
@@ -574,51 +603,29 @@ def simulate_batch(traces, prms: Sequence[SimParams], *,
         per = [_host_args(t, p, use_sched) for t, p in zip(traces, prms)]
         targs = [np.stack([h[i] for h in per]) for i in range(len(per[0]))]
 
-    ndev = len(jax.devices())
-    if chunk is not None and 0 < chunk < B:
-        n_chunks = -(-B // chunk)
-        batched = ([dyn] if shared else targs + [dyn])
-        batched = _pad_batch(batched, n_chunks * chunk - B)
-        batched = [a.reshape((n_chunks, chunk) + a.shape[1:])
-                   for a in batched]
-        if shard and ndev > 1 and chunk % ndev == 0:
-            mesh = jax.sharding.Mesh(np.array(jax.devices()), ("batch",))
-            spec = jax.sharding.NamedSharding(
-                mesh, jax.sharding.PartitionSpec(None, "batch"))
-            batched = [jax.device_put(a, spec) for a in batched]
+    ndev = len(jax.devices()) if shard else 1
+    chunked = chunk is not None and 0 < chunk < B
+    if chunked:
+        C = -(-chunk // ndev) * ndev          # every chunk splits evenly
+        lead = (-(-B // C), C)
+    else:
+        lead = (-(-B // ndev) * ndev,)
+    batched = _pad_batch([dyn] if shared else targs + [dyn],
+                         int(np.prod(lead)) - B)
+    batched = [a.reshape(lead + a.shape[1:]) for a in batched]
+    host = tuple(targs) if shared else tuple(batched[:-1])
+    args = list(_to_device_args(env, host, batched[-1], use_sched))
+    first = len(args) - 1 if shared else 0
+    if ndev > 1:
+        sharding = batch_sharding(lead[-1], axis=len(lead) - 1)
+        args[first:] = [jax.device_put(a, sharding) for a in args[first:]]
+    if chunked:
         fn = _chunked_jitted(env, use_sched, shared)
-        if shared:
-            dev = _to_device_args(env, tuple(targs), batched[0], use_sched)
-            out = fn(*dev)
-        else:
-            out = fn(*_to_device_args(env, tuple(batched[:-1]), batched[-1],
-                                      use_sched))
-        out = jax.tree_util.tree_map(
-            lambda a: np.asarray(a).reshape((n_chunks * chunk,)
-                                            + a.shape[2:])[:B], out)
-        return out
-
-    if shared:
-        sharding = batch_sharding(B) if shard else None
-        dev = list(_to_device_args(env, tuple(targs), dyn, use_sched))
-        if sharding is not None:
-            dev[-1] = jax.device_put(dev[-1], sharding)
+    elif shared:
         fn = _shared_batch_jitted(env, use_sched)
-        out = fn(*dev)
-        return jax.tree_util.tree_map(np.asarray, out)
-
-    pad = (-B) % ndev if (shard and ndev > 1) else 0
-    stacked = _pad_batch(targs + [dyn], pad)
-    args = list(_to_device_args(env, tuple(stacked[:-1]), stacked[-1],
-                                use_sched))
-    sharding = batch_sharding(B + pad) if shard else None
-    if sharding is not None:
-        args = [jax.device_put(a, sharding) for a in args]
-    fn = (_sched_batch_jitted(env) if use_sched else _batch_jitted(env))
-    out = fn(*args)
-    if pad:
-        out = jax.tree_util.tree_map(lambda a: a[:B], out)
-    return jax.tree_util.tree_map(np.asarray, out)
+    else:
+        fn = _sched_batch_jitted(env) if use_sched else _batch_jitted(env)
+    return PreparedBatch(fn, tuple(args), B, len(lead), first)
 
 
 def _static_prm(prm: SimParams) -> SimParams:
@@ -655,38 +662,29 @@ def _chunked_jitted(prm: SimParams, use_sched: bool, shared: bool):
     return _chunked_jitted_cached(_static_prm(prm), use_sched, shared)
 
 
-def _donate() -> tuple:
-    """Donate the jitted cores' input buffers (fresh host arrays every call)
-    — except on CPU, where XLA cannot consume donations and would warn."""
-    return tuple(range(8)) if jax.default_backend() != "cpu" else ()
-
-
 @lru_cache(maxsize=32)
 def _core_jitted_cached(prm: SimParams):
-    return jax.jit(partial(_core, prm=prm), donate_argnums=_donate())
+    return jax.jit(partial(_core, prm=prm))
 
 
 @lru_cache(maxsize=32)
 def _batch_jitted_cached(prm: SimParams):
-    return jax.jit(jax.vmap(partial(_core, prm=prm)),
-                   donate_argnums=_donate())
+    return jax.jit(jax.vmap(partial(_core, prm=prm)))
 
 
 @lru_cache(maxsize=32)
 def _sched_jitted_cached(prm: SimParams):
-    return jax.jit(partial(_core_sched, prm=prm), donate_argnums=_donate())
+    return jax.jit(partial(_core_sched, prm=prm))
 
 
 @lru_cache(maxsize=32)
 def _sched_batch_jitted_cached(prm: SimParams):
-    return jax.jit(jax.vmap(partial(_core_sched, prm=prm)),
-                   donate_argnums=_donate())
+    return jax.jit(jax.vmap(partial(_core_sched, prm=prm)))
 
 
 @lru_cache(maxsize=32)
 def _shared_batch_jitted_cached(prm: SimParams, use_sched: bool):
-    """One trace broadcast across every point: only ``dyn`` is batched
-    (no donation — the trace buffers are reused across calls)."""
+    """One trace broadcast across every point: only ``dyn`` is batched."""
     core = partial(_core_sched if use_sched else _core, prm=prm)
     return jax.jit(jax.vmap(core, in_axes=(None,) * 7 + (0,)))
 

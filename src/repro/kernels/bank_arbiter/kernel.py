@@ -7,7 +7,8 @@ with no data movement, exactly the "keep the hot dataflow on-chip" shape the
 dataflow-accelerator literature argues for.  The kernel evaluates it as a
 dense comparator tree on the VPU:
 
-  * the grid tiles banks ``BANK_BLOCK`` at a time (one output row each);
+  * the grid tiles banks ``BANK_BLOCK`` at a time (one lane-tile of the
+    ``[1, NB]`` output row each);
   * slots arrive as a ``[S/LANES, LANES]`` layout held entirely in VMEM —
     per grid step a ``fori_loop`` walks the slot rows, comparing each
     ``[1, LANES]`` row against the step's ``[BANK_BLOCK, 1]`` bank ids and
@@ -20,8 +21,8 @@ within a row the masked ``min`` picks the lowest lane, across rows an equal
 key never replaces the earlier (lower-id) winner.
 
 The kernel is bit-exact against ``ref.bank_arbiter_ref`` (hypothesis-tested
-grant-for-grant) and runs under ``interpret=True`` on CPU — the container's
-fallback path — with identical results.
+grant-for-grant).  It compiles for TPU; on CPU it runs under
+``interpret=True`` (``ops.pallas_interpret``) with identical results.
 """
 from __future__ import annotations
 
@@ -98,9 +99,10 @@ def bank_arbiter(key, bank, *, num_banks: int, num_slots: int,
         grid=(NBp // BANK_BLOCK,),
         in_specs=[pl.BlockSpec((nrows, LANES), lambda i: (0, 0)),
                   pl.BlockSpec((nrows, LANES), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((1, BANK_BLOCK), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((NBp // BANK_BLOCK, BANK_BLOCK),
-                                       jnp.int32),
+        # one [1, NBp] row, tiled BANK_BLOCK lanes per grid step: a block
+        # row of 1 is legal on TPU only because it spans the whole dimension
+        out_specs=pl.BlockSpec((1, BANK_BLOCK), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, NBp), jnp.int32),
         interpret=interpret,
     )(key2d, bank2d)
     # banks with no eligible slot report num_slots, matching the reference

@@ -3,8 +3,9 @@
 ``bank_arbiter_winners`` is the single entry the simulator's arbitration
 stage calls each cycle.  ``backend="jax"`` (the default) runs the two-pass
 ``segment_min`` reference; ``backend="pallas"`` runs the Pallas comparator
-tree — compiled on TPU, ``interpret=True`` everywhere else (the CPU
-fallback), bit-exact either way.
+tree, compiled on TPU.  The Pallas interpreter is the CPU's way to run the
+same kernel (tests, CPU rehearsals) and is used there only; any other
+backend raises rather than silently interpreting.  Bit-exact either way.
 """
 from __future__ import annotations
 
@@ -15,6 +16,17 @@ from repro.kernels.bank_arbiter.kernel import bank_arbiter
 from repro.kernels.bank_arbiter.ref import KEY_FILLER, bank_arbiter_ref
 
 BACKENDS = ("jax", "pallas")
+
+
+def pallas_interpret() -> bool:
+    """Whether the Pallas kernel runs in the interpreter: on CPU yes, on TPU
+    no (compiled, or the lowering raises).  Read at trace time."""
+    backend = jax.default_backend()
+    if backend in ("cpu", "tpu"):
+        return backend == "cpu"
+    raise NotImplementedError(
+        f"the Pallas bank arbiter compiles for TPU and is interpreted on CPU; "
+        f"backend {backend!r} is neither (use arbiter='jax')")
 
 
 def bank_arbiter_winners(key, bank, elig, *, num_banks: int,
@@ -32,5 +44,4 @@ def bank_arbiter_winners(key, bank, elig, *, num_banks: int,
     masked_bank = jnp.where(elig, bank.astype(jnp.int32), num_banks)
     masked_key = jnp.where(elig, key.astype(jnp.int32), KEY_FILLER)
     return bank_arbiter(masked_key, masked_bank, num_banks=num_banks,
-                        num_slots=S,
-                        interpret=jax.default_backend() != "tpu")
+                        num_slots=S, interpret=pallas_interpret())

@@ -11,7 +11,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 #       --shape train_4k --multi-pod
 #
 # Artifacts: experiments/dryrun/<mesh>/<arch>__<shape>.json — consumed by
-# benchmarks/roofline.py and EXPERIMENTS.md.
+# EXPERIMENTS.md.
 import argparse
 import json
 import re

@@ -39,6 +39,15 @@ def golden_cases():
     ]
 
 
+def golden_batch(cases):
+    """(stacked traces, params) of the pinned batched pair: the two
+    scenario points of ``cases`` as one vmapped scan."""
+    traces = stack_traces([cases[1][1], cases[2][1]])
+    prms = [replace(cases[1][2], max_cycles=4000),
+            replace(cases[2][2], max_cycles=4000)]
+    return traces, prms
+
+
 #: metric keys pinned by the golden file — the pre-refactor output surface
 #: (new slice metrics added later are deliberately NOT pinned)
 GOLDEN_KEYS = (
@@ -58,11 +67,7 @@ def main() -> None:
     for name, trace, prm in golden_cases():
         out["cases"][name] = _jsonable(simulate(trace, prm))
     # the batched path: two scenario points, one vmapped scan
-    cases = golden_cases()
-    traces = stack_traces([cases[1][1], cases[2][1]])
-    prms = [replace(cases[1][2], max_cycles=4000),
-            replace(cases[2][2], max_cycles=4000)]
-    out["batch"] = _jsonable(simulate_batch(traces, prms))
+    out["batch"] = _jsonable(simulate_batch(*golden_batch(golden_cases())))
     path = Path(__file__).parent / "golden_single_slice.json"
     path.write_text(json.dumps(out))
     print(f"wrote {path} ({path.stat().st_size} bytes)")

@@ -242,7 +242,8 @@ def test_fuzz_driver_writes_reproducers_and_fails(tmp_path):
          "--max-cycles", "6000", "--geometries", "small16",
          "--out-dir", str(out_dir), "--quiet"],
         capture_output=True, text=True, cwd=Path(__file__).parent.parent,
-        env={**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"})
+        env={**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu",
+             "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "jax_cache")})
     assert proc.returncode == 1, proc.stderr[-2000:]
     summary = json.loads((out_dir / "fuzz_summary.json").read_text())
     assert summary["violations"] >= 1

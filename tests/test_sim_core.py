@@ -34,7 +34,7 @@ def _random_arb_inputs(rng, S, NB, age_cap, X):
 
 
 # ---------------------------------------------------------------------------
-# bank-arbiter kernel parity (interpret mode — the CPU fallback path)
+# bank-arbiter kernel parity (interpret mode — how the CPU runs the kernel)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("S,NB,X", [(64, 16, 4), (256, 256, 8),
@@ -79,6 +79,18 @@ def test_bank_arbiter_unknown_backend_raises():
     z = jnp.zeros((8,), jnp.int32)
     with pytest.raises(ValueError, match="unknown bank-arbiter backend"):
         bank_arbiter_winners(z, z, z > 0, num_banks=4, backend="verilog")
+
+
+def test_pallas_interpreter_is_cpu_only(monkeypatch):
+    """The Pallas arbiter is interpreted on CPU, compiled on TPU, and
+    refused anywhere else — never a silent interpreter on an accelerator."""
+    from repro.kernels.bank_arbiter import ops
+    assert ops.pallas_interpret() is (jax.default_backend() == "cpu")
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    assert ops.pallas_interpret() is False
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(NotImplementedError, match="gpu"):
+        ops.pallas_interpret()
 
 
 def test_bank_arbiter_hypothesis_parity():

@@ -18,7 +18,7 @@ from repro.core.address import MemoryGeometry, master_home_slices
 from repro.core.qos import regions_isolated
 from repro.core.simulator import (SimParams, Trace, batch_sharding, simulate,
                                   simulate_batch)
-from repro.core.traffic import pad_trace, stack_traces
+from repro.core.traffic import pad_trace
 from repro.scenarios import (MasterSpec, Scenario, SweepPoint,
                              run_sweep, slice_scaling)
 from repro.scenarios.spec import resolve_regions
@@ -55,7 +55,8 @@ def test_single_slice_outputs_match_pre_refactor_goldens():
     tests/data/capture_golden.py)."""
     sys.path.insert(0, str(DATA))
     try:
-        from capture_golden import GOLDEN_KEYS, _jsonable, golden_cases
+        from capture_golden import (GOLDEN_KEYS, _jsonable, golden_batch,
+                                    golden_cases)
     finally:
         sys.path.pop(0)
     golden = json.loads((DATA / "golden_single_slice.json").read_text())
@@ -63,11 +64,7 @@ def test_single_slice_outputs_match_pre_refactor_goldens():
         got = _jsonable(simulate(trace, prm))
         for k in GOLDEN_KEYS:
             assert got[k] == golden["cases"][name][k], (name, k)
-    cases = golden_cases()
-    traces = stack_traces([cases[1][1], cases[2][1]])
-    prms = [replace(cases[1][2], max_cycles=4000),
-            replace(cases[2][2], max_cycles=4000)]
-    got = _jsonable(simulate_batch(traces, prms))
+    got = _jsonable(simulate_batch(*golden_batch(golden_cases())))
     for k in GOLDEN_KEYS:
         assert got[k] == golden["batch"][k], ("batch", k)
 
@@ -254,51 +251,71 @@ def test_batch_sharding_single_device_falls_back():
     import jax
     n = len(jax.devices())
     if n == 1:
-        assert batch_sharding(4) is None      # graceful single-device path
+        assert batch_sharding(4) is None      # nothing to split on one device
     else:
-        assert batch_sharding(n + 1) is None  # non-divisible batch: no shard
+        with pytest.raises(ValueError, match="does not split"):
+            batch_sharding(n + 1)             # callers pad; never one device
 
 
-def test_sharded_batch_matches_unsharded_across_devices():
-    """Force 2 host devices in a subprocess (the flag must precede jax
-    import) and check the sharded batch is bit-identical to unsharded —
-    including a NON-divisible batch (padded up to the device multiple and
-    sliced back, not silently single-devices) and a chunked run whose
-    per-chunk axis is sharded."""
-    prog = """
+_SHARD_PROG = """
+import sys
 import numpy as np, jax
-assert len(jax.devices()) == 2, jax.devices()
-from repro.core.simulator import SimParams, Trace, batch_sharding, simulate_batch
+ND = int(sys.argv[1])
+assert len(jax.devices()) == ND, jax.devices()
+from repro.core.simulator import (SCHEDULE_PIPELINE, SimParams, Trace,
+                                  batch_sharding, prepare_batch,
+                                  simulate_batch)
 rng = np.random.default_rng(0)
 X, N = 4, 16
 traces = [Trace(np.zeros((X, N), np.int32), np.full((X, N), 8, np.int32),
                 rng.integers(0, 2**18, (X, N)).astype(np.int32))
-          for _ in range(4)]
-prms = [SimParams(max_cycles=800)] * 4
-assert batch_sharding(4) is not None
-assert batch_sharding(3) is None
-s = simulate_batch(traces, prms, shard=True)
-u = simulate_batch(traces, prms, shard=False)
-for k in s:
-    assert np.array_equal(s[k], u[k]), k
-# non-divisible batch: padded to the device multiple, sliced back to B=3
-s3 = simulate_batch(traces[:3], prms[:3], shard=True)
-u3 = simulate_batch(traces[:3], prms[:3], shard=False)
-for k in s3:
-    assert np.asarray(s3[k]).shape[0] == 3, k
-    assert np.array_equal(s3[k], u3[k]), k
-# chunked + sharded (chunk divisible by device count)
-c = simulate_batch(traces, prms, shard=True, chunk=2)
-for k in c:
-    assert np.array_equal(c[k], u[k]), k
+          for _ in range(5)]
+prms = [SimParams(max_cycles=800, outstanding=o) for o in (8, 4, 2, 6, 3)]
+assert batch_sharding(ND) is not None
+try:
+    batch_sharding(ND + 1)
+    raise AssertionError("a non-divisible batch must not shard silently")
+except ValueError:
+    pass
+
+def check(tr, pr, **kw):
+    pb = prepare_batch(tr, pr, shard=True, **kw)
+    held = {s.device for a in pb.args[pb.batched:]
+            for s in a.addressable_shards}
+    assert held == set(jax.devices()), (len(tr), len(pr), kw, held)
+    s = pb.run()
+    u = simulate_batch(tr, pr, shard=False, **kw)
+    for k in u:
+        assert np.asarray(s[k]).shape[0] == len(pr), k
+        assert np.array_equal(s[k], u[k]), (len(tr), len(pr), kw, k)
+
+check(traces[:4], prms[:4])                  # divisible batch
+check(traces[:3], prms[:3])                  # padded to the device multiple
+check(traces[:1], prms[:3])                  # shared trace, B % ND != 0
+check(traces[:1], prms, chunk=3)             # shared, chunk % ND != 0
+check(traces, prms, chunk=3)                 # per-point traces, chunk % ND
+check(traces, prms, chunk=ND)                # chunk divisible by ND
+stream = [SimParams(max_cycles=800, outstanding=o, stages=SCHEDULE_PIPELINE,
+                    collect="stream") for o in (8, 4, 2, 6, 3)]
+check(traces[:1], stream, chunk=3)           # the scale-sweep path
 print("OK")
 """
-    env = {"XLA_FLAGS": "--xla_force_host_platform_device_count=2",
+
+
+@pytest.mark.parametrize("ndev", [2, 4])
+def test_sharded_batch_matches_unsharded_across_devices(ndev):
+    """Force ``ndev`` host devices in a subprocess (the flag must precede
+    jax import) and check every sharded batch path is bit-identical to the
+    unsharded run and really holds a shard on every device: divisible and
+    NON-divisible batches (padded up to the device multiple and sliced
+    back), a shared trace with B not divisible by the device count, and
+    chunked runs whose chunk the device count does and does not divide."""
+    env = {"XLA_FLAGS": f"--xla_force_host_platform_device_count={ndev}",
            "JAX_PLATFORMS": "cpu",
            "PYTHONPATH": str(REPO / "src"),
            "PATH": "/usr/local/bin:/usr/bin:/bin"}
-    res = subprocess.run([sys.executable, "-c", prog], env=env,
-                         capture_output=True, text=True, timeout=300)
+    res = subprocess.run([sys.executable, "-c", _SHARD_PROG, str(ndev)],
+                         env=env, capture_output=True, text=True, timeout=600)
     assert res.returncode == 0, res.stderr
     assert "OK" in res.stdout
 
@@ -414,3 +431,23 @@ def test_bench_cli_lists_jobs_and_rejects_unknown():
     assert bad.returncode != 0
     assert "definitely_not_a_job" in bad.stderr
     assert "slice_scaling" in bad.stderr      # the valid list is shown
+
+
+def test_compile_cache_placement(monkeypatch):
+    """Entry points keep JAX's compile cache where JAX_COMPILATION_CACHE_DIR
+    says, else at one fixed path in the checkout; nothing else is set."""
+    import jax
+
+    from repro.compile_cache import CACHE_DIR, use_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert use_compile_cache() == "/elsewhere/cache"
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        assert use_compile_cache() == str(CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(CACHE_DIR)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert CACHE_DIR == REPO / ".jax_cache"
+    assert ".jax_cache/" in (REPO / ".gitignore").read_text().split()
