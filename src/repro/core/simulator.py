@@ -448,12 +448,16 @@ def simulate(trace, prm: SimParams = SimParams()) -> Dict[str, np.ndarray]:
     pipeline (``SCHEDULE_PIPELINE`` advances schedules in-scan, the default
     dense pipeline precomputes beat tables) and inputs are converted to
     match."""
-    use_sched = prm.uses_schedule()
-    t = _as_input(trace, use_sched)
-    fn = _sched_jitted(prm) if use_sched else _core_jitted(prm)
-    out = fn(*_to_device_args(prm, _host_args(t, prm, use_sched),
-                              prm.dyn_vector(), use_sched))
-    return jax.tree_util.tree_map(np.asarray, out)
+    with jax.profiler.TraceAnnotation("repro.simulate"):
+        use_sched = prm.uses_schedule()
+        with jax.profiler.TraceAnnotation("repro.prepare"):
+            t = _as_input(trace, use_sched)
+            args = _to_device_args(prm, _host_args(t, prm, use_sched),
+                                   prm.dyn_vector(), use_sched)
+        fn = _sched_jitted(prm) if use_sched else _core_jitted(prm)
+        out = fn(*args)
+        with jax.profiler.TraceAnnotation("repro.fetch"):
+            return jax.tree_util.tree_map(np.asarray, out)
 
 
 def compile_simulate(trace, prm: SimParams):
@@ -540,9 +544,10 @@ class PreparedBatch:
 
     def run(self) -> Dict[str, np.ndarray]:
         out = self.fn(*self.args)
-        return jax.tree_util.tree_map(
-            lambda a: np.asarray(a).reshape((-1,) + a.shape[self.lead:])
-            [:self.size], out)
+        with jax.profiler.TraceAnnotation("repro.fetch"):
+            return jax.tree_util.tree_map(
+                lambda a: np.asarray(a).reshape((-1,) + a.shape[self.lead:])
+                [:self.size], out)
 
 
 def simulate_batch(traces, prms: Sequence[SimParams], *,
@@ -574,7 +579,10 @@ def simulate_batch(traces, prms: Sequence[SimParams], *,
       chunk the device count does not divide is padded up to the device
       multiple and sliced back, on every path.
     """
-    return prepare_batch(traces, prms, shard=shard, chunk=chunk).run()
+    with jax.profiler.TraceAnnotation("repro.simulate"):
+        with jax.profiler.TraceAnnotation("repro.prepare"):
+            prepared = prepare_batch(traces, prms, shard=shard, chunk=chunk)
+        return prepared.run()
 
 
 def prepare_batch(traces, prms: Sequence[SimParams], *, shard: bool = True,
@@ -1453,13 +1461,16 @@ def _dense_setup(tx_write, tx_burst, tx_banks, tx_hops, tx_ing, tx_start,
 
 
 def _pipeline_cycle(prm: SimParams, ctx):
-    """One full pipeline pass as a scan body ``cycle(state, _)``."""
-    stage_fns = [STAGE_REGISTRY[name] for name in prm.pipeline()]
+    """One full pipeline pass as a scan body ``cycle(state, _)``.  Each
+    stage's operations carry the name scope ``stage.<registry name>`` in
+    their metadata, which a profiler trace of the compiled program keeps."""
+    stage_fns = [(name, STAGE_REGISTRY[name]) for name in prm.pipeline()]
 
     def cycle(st, _):
         wires: dict = {}
-        for fn in stage_fns:
-            st, wires = fn(st, wires, ctx)
+        for name, fn in stage_fns:
+            with jax.named_scope(f"stage.{name}"):
+                st, wires = fn(st, wires, ctx)
         return st, None
 
     return cycle

@@ -1,4 +1,4 @@
-"""Compile the main path's kernel and schedule core for a described TPU v5e.
+"""Compile the main path's kernel and cores for a described TPU v5e.
 
 Nothing runs here: JAX compiles for a ``v5e:2x2`` topology that is described,
 not attached, so whatever the chip's compiler refuses (a block shape off the
@@ -7,6 +7,7 @@ that passes is not a chip run.  The topology is described inside a fixture,
 never at import, and every test of this kind stays in this one file.
 """
 import os
+import re
 from functools import partial
 
 import jax
@@ -16,7 +17,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import simulator
-from repro.core.simulator import SCHEDULE_PIPELINE, SimParams
+from repro.core.simulator import (DEFAULT_PIPELINE, SCHEDULE_PIPELINE,
+                                  SimParams)
 from repro.kernels.bank_arbiter import ops
 from repro.kernels.bank_arbiter.kernel import bank_arbiter
 from repro.scenarios import urban_perception
@@ -80,3 +82,30 @@ def test_schedule_core_compiles_with_pallas_arbiter(one_chip, monkeypatch):
     compiled = core.lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis() is not None
+
+
+def test_dense_core_fusions_name_every_stage_for_v5e(one_chip):
+    """The dense core at the Fig. 4 shapes (32 ports x 500 transactions,
+    ``max_burst`` 16): each stage of the default pipeline owns at least one
+    fusion by its own ``op_name``, so a chip trace can put the device time
+    of each stage down to it."""
+    rng = np.random.default_rng(0)
+    X, N = 32, 500
+    trace = simulator.Trace(
+        is_write=np.repeat([[0], [1]], X // 2, axis=0) * np.ones((1, N), int),
+        burst=np.full((X, N), 16), addr=rng.integers(0, 1 << 19, (X, N)),
+        prio=np.zeros(X, int))
+    prm = simulator._static_prm(SimParams(max_cycles=22_800))
+    dev = simulator._device_args(prm, *simulator._host_args(trace, prm, False),
+                                 prm.dyn_vector())
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+            for a in dev]
+    core = jax.jit(partial(simulator._core, prm=prm))
+    text = core.lower(*args).compile().as_text()
+    owners = set()
+    for line in text.splitlines():
+        own = re.search(r'op_name="([^"]*)"', line)
+        if " fusion(" in line and own:
+            owners.update(c for c in own.group(1).split("/")
+                          if c.startswith("stage."))
+    assert {f"stage.{name}" for name in DEFAULT_PIPELINE} <= owners
