@@ -42,7 +42,8 @@ stage functions widen fields to int32 views on read and narrow on write, so
 arithmetic semantics are unchanged.  Beat slots are laid out ``[X, P]``
 (port-major), which turns the per-port return bus and dispatch ring into
 dense vector ops along the ``P`` axis; only per-bank arbitration reduces
-across ports, via one flat comparator-tree call.
+across ports: on the chip through dense [X, NB, P] bank masks, on a CPU
+through one flat comparator-tree call.
 
 The cycle body is a *stage registry*: each stage is registered by name
 (:func:`register_stage`) with the uniform signature
@@ -61,8 +62,9 @@ configuration instead of by editing ``cycle()``:
   ``retire``          transaction completion + busy-cycle accounting
 
 The per-bank comparator tree itself is a swappable backend
-(``SimParams.arbiter``): ``"jax"`` runs the two-pass ``segment_min``
-reference, ``"pallas"`` the Pallas TPU kernel
+(``SimParams.arbiter``): ``"jax"`` runs one masked (key, slot)
+min-reduction per bank on the chip and the two-pass ``segment_min``
+reference on a CPU, ``"pallas"`` the Pallas TPU kernel
 (``kernels/bank_arbiter/``; compiled on TPU, interpreted on CPU) —
 bit-exact either way (hypothesis-tested grant-for-grant).
 
@@ -106,7 +108,8 @@ from repro.core.qos import aging_boost, arbitration_priority_key
 from repro.core.state import (INF32, SLOT_GRANTED, SLOT_IDLE, SLOT_WAITING,
                               SimState, bank_dtype, init_state,
                               pack_slot_flags, unpack_slot_flags, widen)
-from repro.kernels.bank_arbiter.ops import bank_arbiter_winners
+from repro.kernels.bank_arbiter.ops import bank_arbiter_winners, bid_winners
+from repro.kernels.bank_arbiter.ref import KEY_FILLER
 
 #: SimParams fields that enter the scan as traced *values* (per-point in a
 #: batched sweep).  Order defines the layout of the ``dyn`` vector.
@@ -930,6 +933,42 @@ def _stage_accept_dispatch(st: SimState, wires, c):
     return _stage_dispatch(st, wires, c)
 
 
+def _arbiter_by_mask(bank, key, waiting, bank_free, bank_rr, now, *, c):
+    """Winners and grants with every per-slot bank lookup as a dense mask:
+    ``hit`` [X, NB, P] compares every bank id with each slot's bank (ring
+    slots on the lane axis), and the bank's free flag, its round-robin term
+    (an [X, NB] table — it depends on the port and the bank alone) and its
+    winner reach the slots through it as compare, select and reduce, with
+    no gather or scatter over the slots.  Returns (win [NB], granted
+    [X, P])."""
+    X, NB = c["X"], c["NB"]
+    banks = jnp.arange(NB, dtype=jnp.int32)[:, None]
+    hit = (bank[:, None, :] == banks) & (bank_free <= now)[:, None]
+    rr = (c["master_col"] - bank_rr[None, :]) % X             # [X, NB]
+    # a slot that is not waiting bids the filler plus its rr: no bid
+    key = jnp.where(waiting, key, KEY_FILLER)
+    bid = jnp.where(hit, key[:, None, :] + rr[:, :, None], KEY_FILLER)
+    win = bid_winners(bid, bank, backend=c["prm"].arbiter)    # [NB]
+    # a slot is granted iff it IS some bank's winner (a winner bids for its
+    # own bank alone; a bank with no eligible slot reports the sentinel S)
+    granted = jnp.any(c["flat_ids"][:, None, :] == win[:, None], axis=1)
+    return win, granted
+
+
+def _arbiter_by_lookup(bank, key, waiting, bank_free, bank_rr, now, *, c):
+    """:func:`_arbiter_by_mask` by per-slot lookups into the [NB] tables and
+    the flat comparator tree (``segment_min`` on the jax backend): the CPU
+    lowering, where a lookup costs a few ns a slot and the mask NB times
+    as many element operations.  Bit-identical results."""
+    X, S, NB = c["X"], c["S"], c["NB"]
+    elig = waiting & (bank_free[bank] <= now)
+    key = key + (c["master_col"] - bank_rr[bank]) % X
+    win = bank_arbiter_winners(key.reshape(S), bank.reshape(S),
+                               elig.reshape(S), num_banks=NB,
+                               backend=c["prm"].arbiter)      # [NB]
+    return win, c["flat_ids"] == win[bank]
+
+
 @register_stage("bank_arbitrate")
 def _stage_bank_arbitrate(st: SimState, wires, c):
     """Per-bank arbitration, one grant per bank per cycle: priority level
@@ -939,32 +978,29 @@ def _stage_bank_arbitrate(st: SimState, wires, c):
     masters as the tie-break.  A granted read's data heads home after the
     bank's access latency plus the router's return-path hops.
 
-    The comparator tree runs as one ``bank_arbiter_winners`` call
-    (``SimParams.arbiter`` picks the jax reference or the Pallas kernel);
-    every piece of bookkeeping then derives from the [NB] winner view —
-    per-slot work is one gather + compare."""
-    X, P, S, NB = c["X"], c["P"], c["S"], c["NB"]
-    prm, d = c["prm"], c["d"]
+    The winners and grants come from :func:`_arbiter_by_mask` on the chip
+    and :func:`_arbiter_by_lookup` on a CPU, chosen when the program is
+    lowered for its platform (``SimParams.arbiter`` picks the jax reduction
+    or the Pallas kernel in either); every piece of bookkeeping then
+    derives from the [NB] winner view."""
+    X, P, S = c["X"], c["P"], c["S"]
+    d = c["d"]
     now = st.now
     phase, write = unpack_slot_flags(st.sl_flags)
-    bank = widen(st.sl_bank)                                  # [X, P]
     waiting = (phase == SLOT_WAITING) & (st.sl_arrive <= now)
-    elig = waiting & (st.bank_free[bank] <= now)
     age = jnp.clip(now - st.sl_arrive, 0, c["AGE_CAP"])
     boost = aging_boost(age, d["qos_aging"])
     level = jnp.clip(c["slot_prio"] - boost, 0, PRIO_LEVELS - 1)
-    rr = (c["master_col"] - st.bank_rr[bank]) % X
-    key = arbitration_priority_key(level, age, rr, age_cap=c["AGE_CAP"],
+    # the round-robin term is added per bank by the arbiter
+    key = arbitration_priority_key(level, age, 0, age_cap=c["AGE_CAP"],
                                    num_masters=X)
-    win = bank_arbiter_winners(key.reshape(S), bank.reshape(S),
-                               elig.reshape(S), num_banks=NB,
-                               backend=prm.arbiter)           # [NB]
+    win, granted = jax.lax.platform_dependent(
+        widen(st.sl_bank), key, waiting, st.bank_free, st.bank_rr, now,
+        cpu=partial(_arbiter_by_lookup, c=c),
+        default=partial(_arbiter_by_mask, c=c))
     has_win = win < S
     winc = jnp.minimum(win, S - 1)
     wmaster = winc // P
-    # a slot is granted iff it IS its bank's winner (winners are eligible by
-    # construction; a bank with no eligible slot reports the sentinel S)
-    granted = c["flat_ids"] == win[bank]                      # [X, P]
     wwrite = write.reshape(S)[winc]
     occ = d["bank_occupancy"]
     bank_free = jnp.where(has_win, jnp.maximum(st.bank_free, now) + occ,

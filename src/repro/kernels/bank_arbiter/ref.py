@@ -9,7 +9,10 @@ The contract shared with the Pallas kernel (``kernel.py``):
   index; ``num_slots`` when the bank has no eligible slot.
 
 This is exactly the two-pass ``segment_min`` the pre-refactor arbitration
-stage inlined, and it is the simulator's default arbiter backend.
+stage inlined.  It is the tests' oracle and the ``"jax"`` backend of the
+flat ``ops.bank_arbiter_winners``, which only the arbitration stage's CPU
+lowering calls: on the chip the stage resolves a dense bid matrix
+(``ops.dense_bank_winners``) with no scatter.
 """
 from __future__ import annotations
 

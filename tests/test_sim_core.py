@@ -147,6 +147,176 @@ def test_full_sim_pallas_arbiter_bit_exact(rng):
 
 
 # ---------------------------------------------------------------------------
+# the arbitration stage's dense bank masks against per-slot lookups
+# ---------------------------------------------------------------------------
+
+def _lookup_arbitrate(st, wires, c):
+    """The arbitration stage as per-slot lookups: ``bank_free[bank]``,
+    ``bank_rr[bank]``, the flat comparator tree (``segment_min`` on the jax
+    backend) and ``win[bank]`` — the oracle for the dense masks."""
+    from repro.core.qos import aging_boost
+    from repro.core.simulator import PRIO_LEVELS
+    from repro.core.state import SLOT_GRANTED, SLOT_WAITING, widen
+    X, P, S, NB = c["X"], c["P"], c["S"], c["NB"]
+    d, now = c["d"], st.now
+    phase, write = unpack_slot_flags(st.sl_flags)
+    bank = widen(st.sl_bank)
+    waiting = (phase == SLOT_WAITING) & (st.sl_arrive <= now)
+    elig = waiting & (st.bank_free[bank] <= now)
+    age = jnp.clip(now - st.sl_arrive, 0, c["AGE_CAP"])
+    level = jnp.clip(c["slot_prio"] - aging_boost(age, d["qos_aging"]), 0,
+                     PRIO_LEVELS - 1)
+    rr = (c["master_col"] - st.bank_rr[bank]) % X
+    key = arbitration_priority_key(level, age, rr, age_cap=c["AGE_CAP"],
+                                   num_masters=X)
+    if c["prm"].arbiter == "jax":
+        win = bank_arbiter_ref(key.reshape(S), bank.reshape(S),
+                               elig.reshape(S), num_banks=NB)
+    else:
+        win = bank_arbiter_winners(key.reshape(S), bank.reshape(S),
+                                   elig.reshape(S), num_banks=NB,
+                                   backend=c["prm"].arbiter)
+    has_win = win < S
+    winc = jnp.minimum(win, S - 1)
+    wmaster = winc // P
+    granted = c["flat_ids"] == win[bank]
+    wwrite = write.reshape(S)[winc]
+    occ = d["bank_occupancy"]
+    owner = has_win[None, :] & (wmaster[None, :] == c["ar"][:, None])
+    freed = jnp.stack([jnp.sum(owner & (wwrite[None, :] == w), axis=1,
+                               dtype=jnp.int32) for w in (0, 1)], axis=1)
+    st2 = st.replace(
+        bank_free=jnp.where(has_win, jnp.maximum(st.bank_free, now) + occ,
+                            st.bank_free),
+        bank_rr=jnp.where(has_win, st.bank_rr + (wmaster - st.bank_rr) % X
+                          + 1, st.bank_rr),
+        sl_flags=pack_slot_flags(jnp.where(granted, SLOT_GRANTED, phase),
+                                 write),
+        sl_ready=jnp.where(granted, now + occ + d["bank_latency"]
+                           + d["hop_latency"] * widen(st.sl_hops),
+                           st.sl_ready),
+        credits=st.credits + freed.astype(st.credits.dtype))
+    arb = dict(has_win=has_win, wmaster=wmaster, wwrite=wwrite,
+               whops=widen(st.sl_hops).reshape(S)[winc],
+               wtxn=widen(st.sl_txn).reshape(S)[winc])
+    return st2, dict(wires, arb=arb)
+
+
+def _random_slot_state(rng, NB, arbiter, X=4, P=64, N=6):
+    """A dense-pipeline state with random slot contents: every phase,
+    arrivals in the past and the future (few distinct ages, so keys tie
+    within and across ports), busy and free banks, and half the banks with
+    no slot."""
+    from repro.core.address import MemoryGeometry
+    from repro.core.simulator import (_dense_setup, _device_args,
+                                      _host_args, _static_prm)
+    prm = _static_prm(SimParams(
+        geom=MemoryGeometry(num_slices=NB // 256), slots_override=P,
+        max_cycles=4000, arbiter=arbiter, qos_aging=4))
+    t = Trace(is_write=rng.integers(0, 2, (X, N)),
+              burst=rng.integers(1, 9, (X, N)),
+              addr=rng.integers(0, 3000, (X, N)),
+              prio=rng.integers(0, 2, X))
+    dyn = replace(prm, qos_aging=4, hop_latency=6).dyn_vector()
+    st, ctx = _dense_setup(*_device_args(prm, *_host_args(t, prm, False),
+                                         dyn), prm)
+    now = 1000
+    # slots target half the banks, a quarter of them eight hot banks, so
+    # many slots of one port and of several ports meet at one bank
+    used = rng.choice(NB, NB // 2, replace=False)
+    hot = rng.random((X, P)) < 0.25
+    st = st.replace(
+        now=jnp.int32(now),
+        sl_flags=pack_slot_flags(jnp.asarray(rng.integers(0, 3, (X, P))),
+                                 jnp.asarray(rng.integers(0, 2, (X, P)))),
+        sl_bank=jnp.asarray(used[np.where(hot, rng.integers(0, 8, (X, P)),
+                                          rng.integers(0, NB // 2, (X, P)))],
+                            st.sl_bank.dtype),
+        sl_arrive=jnp.asarray(now + rng.choice([-40, -9, -1, 0, 2], (X, P)),
+                              jnp.int32),
+        sl_ready=jnp.asarray(rng.integers(0, 2000, (X, P)), jnp.int32),
+        sl_hops=jnp.asarray(rng.integers(0, 3, (X, P)), st.sl_hops.dtype),
+        sl_txn=jnp.asarray(rng.integers(0, N, (X, P)), st.sl_txn.dtype),
+        bank_free=jnp.asarray(now + rng.integers(-3, 3, NB), jnp.int32),
+        bank_rr=jnp.asarray(rng.integers(0, 3 * X, NB), jnp.int32))
+    return st, ctx
+
+
+def _assert_same_stage_out(a, b):
+    for f in dataclasses.fields(SimState):
+        np.testing.assert_array_equal(np.asarray(getattr(a[0], f.name)),
+                                      np.asarray(getattr(b[0], f.name)),
+                                      err_msg=f.name)
+    for k, v in b[1]["arb"].items():
+        np.testing.assert_array_equal(np.asarray(a[1]["arb"][k]),
+                                      np.asarray(v), err_msg=k)
+
+
+@pytest.fixture
+def masked_arbiter(monkeypatch):
+    """Lower the arbitration stage's dense masks on this CPU too (the CPU
+    lowering is per-slot lookups)."""
+    from repro.core import simulator
+    monkeypatch.setattr(simulator, "_arbiter_by_lookup",
+                        simulator._arbiter_by_mask)
+
+
+@pytest.mark.parametrize("arbiter", ["jax", "pallas"])
+@pytest.mark.parametrize("NB", [256, 512, 1024])
+def test_masked_arbitration_matches_lookups(NB, arbiter, rng,
+                                            masked_arbiter):
+    """Dense bank masks against the per-slot lookup oracle: the same
+    SimState and the same arb wires, grant for grant."""
+    from repro.core.simulator import _stage_bank_arbitrate
+    for _ in range(3):
+        st, ctx = _random_slot_state(rng, NB, arbiter)
+        got = jax.jit(lambda s: _stage_bank_arbitrate(s, {}, ctx))(st)
+        want = jax.jit(lambda s: _lookup_arbitrate(s, {}, ctx))(st)
+        assert int(jnp.sum(got[1]["arb"]["has_win"])) > 0
+        assert not bool(jnp.all(got[1]["arb"]["has_win"]))
+        _assert_same_stage_out(got, want)
+
+
+@pytest.mark.parametrize("arbiter", ["jax", "pallas"])
+def test_masked_arbitration_vmap_matches_lookups(arbiter, rng,
+                                                 masked_arbiter):
+    """The dense masks under vmap, as batched and sharded sweeps run them."""
+    from repro.core.simulator import _stage_bank_arbitrate
+    states = [_random_slot_state(rng, 512, arbiter) for _ in range(3)]
+    ctx = states[0][1]
+    batch = jax.tree_util.tree_map(lambda *a: jnp.stack(a),
+                                   *[s for s, _ in states])
+    run = lambda fn: jax.jit(jax.vmap(  # noqa: E731
+        lambda s: fn(s, {}, ctx)))(batch)
+    _assert_same_stage_out(run(_stage_bank_arbitrate), run(_lookup_arbitrate))
+
+
+def test_masked_arbitration_full_sim_bit_exact(rng, monkeypatch):
+    """A whole run lowered with the dense masks on this CPU: every metric
+    matches the per-slot lookups' run."""
+    from functools import partial
+    from repro.core import simulator
+    X, N = 8, 8
+    t = Trace(is_write=rng.integers(0, 2, (X, N)),
+              burst=rng.integers(1, 13, (X, N)),
+              addr=rng.integers(0, 4000, (X, N)),
+              prio=rng.integers(0, 4, X))
+    prm = SimParams(max_cycles=2500, qos_aging=32, reg_rate=64)
+    sprm = simulator._static_prm(prm)
+    args = simulator._device_args(sprm, *simulator._host_args(t, sprm, False),
+                                  prm.dyn_vector())
+    run = lambda: jax.jit(partial(simulator._core, prm=sprm))(*args)  # noqa: E731
+    want = run()
+    monkeypatch.setattr(simulator, "_arbiter_by_lookup",
+                        simulator._arbiter_by_mask)
+    got = run()
+    assert int(got["cycles"]) > 0
+    for k in want:
+        np.testing.assert_array_equal(np.asarray(got[k]),
+                                      np.asarray(want[k]), err_msg=k)
+
+
+# ---------------------------------------------------------------------------
 # stage registry
 # ---------------------------------------------------------------------------
 

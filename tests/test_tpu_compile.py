@@ -84,11 +84,10 @@ def test_schedule_core_compiles_with_pallas_arbiter(one_chip, monkeypatch):
     assert compiled.memory_analysis() is not None
 
 
-def test_dense_core_fusions_name_every_stage_for_v5e(one_chip):
-    """The dense core at the Fig. 4 shapes (32 ports x 500 transactions,
-    ``max_burst`` 16): each stage of the default pipeline owns at least one
-    fusion by its own ``op_name``, so a chip trace can put the device time
-    of each stage down to it."""
+@pytest.fixture(scope="module")
+def fig4_dense_text(one_chip):
+    """The dense core's compiled text at the Fig. 4 shapes (32 ports x 500
+    transactions, ``max_burst`` 16, 32 x 256 = 8,192 ring slots)."""
     rng = np.random.default_rng(0)
     X, N = 32, 500
     trace = simulator.Trace(
@@ -101,11 +100,85 @@ def test_dense_core_fusions_name_every_stage_for_v5e(one_chip):
     args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
             for a in dev]
     core = jax.jit(partial(simulator._core, prm=prm))
-    text = core.lower(*args).compile().as_text()
+    return core.lower(*args).compile().as_text()
+
+
+def test_dense_core_fusions_name_every_stage_for_v5e(fig4_dense_text):
+    """The dense core at the Fig. 4 shapes: each stage of the default
+    pipeline owns at least one fusion by its own ``op_name``, so a chip
+    trace can put the device time of each stage down to it."""
     owners = set()
-    for line in text.splitlines():
+    for line in fig4_dense_text.splitlines():
         own = re.search(r'op_name="([^"]*)"', line)
         if " fusion(" in line and own:
             owners.update(c for c in own.group(1).split("/")
                           if c.startswith("stage."))
     assert {f"stage.{name}" for name in DEFAULT_PIPELINE} <= owners
+
+
+def _arbitration_lookups(text):
+    """(op, result elements) of each gather and scatter that the compiled
+    text puts under ``stage.bank_arbitrate``.  A gather of one element per
+    index has as many result elements as indices."""
+    found = []
+    for line in text.splitlines():
+        m = re.search(r"= \w+\[([\d,]*)\]\S* (gather|scatter)\(", line)
+        if m and "stage.bank_arbitrate" in line:
+            dims = [int(n) for n in m.group(1).split(",") if n]
+            found.append((m.group(2), int(np.prod(dims))))
+    return found
+
+
+@pytest.mark.parametrize("core", ["dense", "schedule"])
+def test_bank_arbitrate_has_no_slot_lookups_for_v5e(core, one_chip,
+                                                    fig4_dense_text):
+    """On the chip the arbitration stage reaches the slots through dense
+    bank masks: no scatter, and no gather with an index per ring slot —
+    only the [NB]-index lookups of the winners' write, hops and txn."""
+    NB = SimParams().geom.num_banks
+    if core == "dense":
+        text, slots = fig4_dense_text, 32 * 256
+    else:
+        sched = urban_perception(txns=256).compile().schedule()
+        prm = simulator._static_prm(SimParams(max_cycles=20_000,
+                                              stages=SCHEDULE_PIPELINE))
+        host = simulator._host_args(sched, prm, True) + (prm.dyn_vector(),)
+        args = [jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype,
+                                     sharding=one_chip) for a in host]
+        core_fn = jax.jit(partial(simulator._core_sched, prm=prm))
+        text = core_fn.lower(*args).compile().as_text()
+        slots = len(sched.burst) * prm.slots_per_master
+    found = _arbitration_lookups(text)
+    assert found, "the stage's [NB]-index gathers are missing"
+    assert all(op == "gather" and n <= NB < slots for op, n in found), found
+
+
+def test_vmapped_bank_arbitrate_fuses_its_masks_for_v5e(one_chip):
+    """The stage vmapped to 64 points at NB = 1024 (four slices) and 8,192
+    ring slots: each [X, NB, P] mask is fused into the reductions that read
+    it.  One such intermediate for the batch would take 512 MiB as pred
+    and 2 GiB as int32; the bound is a sixteenth of the smaller."""
+    from repro.core.address import MemoryGeometry
+    B, X, N = 64, 32, 8
+    rng = np.random.default_rng(0)
+    prm = simulator._static_prm(SimParams(
+        max_cycles=22_800, geom=MemoryGeometry(num_slices=4)))
+    NB, S = prm.geom.num_banks, X * prm.slots_per_master
+    assert (NB, S) == (1024, 8192)
+    trace = simulator.Trace(is_write=rng.integers(0, 2, (X, N)),
+                            burst=np.full((X, N), 16),
+                            addr=rng.integers(0, 1 << 19, (X, N)))
+    dev = simulator._device_args(prm, *simulator._host_args(trace, prm, False),
+                                 prm.dyn_vector())
+
+    def one_point(st, *point):
+        _, ctx = simulator._dense_setup(*point, prm)
+        return simulator._stage_bank_arbitrate(st, {}, ctx)
+
+    state = jax.eval_shape(
+        lambda *p: simulator._dense_setup(*p, prm)[0], *dev)
+    batched = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+        (B,) + a.shape, a.dtype, sharding=one_chip)
+    args = [jax.tree_util.tree_map(batched, state)] + [batched(a) for a in dev]
+    compiled = jax.jit(jax.vmap(one_point)).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes <= B * S * NB // 16
