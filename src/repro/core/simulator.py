@@ -809,48 +809,43 @@ def register_stage(name: str):
     return deco
 
 
-@register_stage("accept")
-def _stage_accept(st: SimState, wires, c):
-    """Command acceptance, one per port per cycle: outstanding credits,
-    split-buffer credits, W-data-bus pacing, the best-effort token-bucket
-    regulator, and the inter-slice router's admission gate (a burst with
-    remote beats needs free ingress credits on every destination slice)."""
-    N = c["N"]
-    d = c["d"]
-    now = st.now
-    ar = c["ar"]
-    nt = st.next_txn
-    has_txn = nt < N
-    nt_c = jnp.minimum(nt, N - 1)
-    burst = widen(c["tx_burst"][ar, nt_c])
-    is_w = widen(c["tx_write"][ar, nt_c])
-    ready = c["tx_start"][ar, nt_c] <= now
-    dirn = is_w  # 0 = read, 1 = write (AXI channels are independent)
-    # token-bucket regulator: a best-effort port must hold tokens for the
-    # whole burst — or a full bucket when the burst exceeds the bucket
-    # depth, in which case the balance goes negative (debt) and the port
-    # stalls until refill repays it, so a burst > reg_burst is delayed,
-    # never deadlocked, and the sustained rate cap still holds
+def _admit(st: SimState, c, due, burst, is_w, need):
+    """The acceptance gates both pipelines share, for each port's next
+    command (``due``: it exists and its issue time has come; ``need``
+    [X, NSL]: its remote beats per destination slice).  Returns (accepted
+    [X], regulator tokens and ingress use after acceptance, and the
+    per-port ``reg_held`` with this cycle's regulator holds added).
+
+    Token-bucket regulator: a best-effort port must hold tokens for the
+    whole burst — or a full bucket when the burst exceeds the bucket depth,
+    in which case the balance goes negative (debt) and the port stalls
+    until refill repays it, so a burst > reg_burst is delayed, never
+    deadlocked, and the sustained rate cap still holds.
+
+    Router admission: every destination slice of the burst's remote beats
+    must have room for them (slice_ingress == 0 disables the cap; local
+    beats need no credit, so a 1-slice fabric never blocks here).  Like the
+    regulator, the per-slice check clamps the requirement to the cap — a
+    burst with more remote beats than slice_ingress is admitted alone and
+    drives the counter into debt (delayed, never deadlocked).  Ports are
+    admitted credit-aware within the cycle: each port also counts the needs
+    of every lower-indexed candidate (an in-order ingress queue, so one
+    admission round cannot oversubscribe a slice beyond the debt allowance;
+    lower port index = admission priority).
+
+    A regulator hold is a port whose due command passes every gate but the
+    token check.  A cycle the time skip jumps has no due command anywhere,
+    so it holds nothing, as the stepped cycle would not have."""
+    d, ar, now = c["d"], c["ar"], st.now
     reg_gate = c["regulated"] & (d["reg_rate"] > 0)
     reg_tokens = jnp.minimum(st.reg_tokens + d["reg_rate"],
                              d["reg_burst"] * REG_SCALE)
     reg_need = jnp.minimum(burst, d["reg_burst"]) * REG_SCALE
-    # router admission: every destination slice of the burst's remote beats
-    # must have room for them (slice_ingress == 0 disables the cap; local
-    # beats need no credit, so a 1-slice fabric never blocks here).  Like
-    # the regulator, the per-slice check clamps the requirement to the cap —
-    # a burst with more remote beats than slice_ingress is admitted alone
-    # and drives the counter into debt (delayed, never deadlocked).  Ports
-    # are admitted credit-aware within the cycle: each port also counts the
-    # needs of every lower-indexed candidate (an in-order ingress queue, so
-    # one admission round cannot oversubscribe a slice beyond the debt
-    # allowance; lower port index = admission priority).
-    need = widen(c["tx_ing"][ar, nt_c])                     # [X, NSL]
-    pre_can = (has_txn & (burst > 0) & ready
-               & (st.outstanding[ar, dirn] < d["outstanding"])
-               & (st.credits[ar, dirn] >= burst)
-               & ((is_w == 0) | (st.fwd_free <= now))
-               & (~reg_gate | (reg_tokens >= reg_need)))
+    tokens_ok = ~reg_gate | (reg_tokens >= reg_need)
+    room = (due & (st.outstanding[ar, is_w] < d["outstanding"])
+            & (st.credits[ar, is_w] >= burst)
+            & ((is_w == 0) | (st.fwd_free <= now)))
+    pre_can = room & tokens_ok
     need_cand = jnp.where(pre_can[:, None], need, 0)
     prior = jnp.cumsum(need_cand, axis=0) - need_cand       # exclusive [X,NSL]
     need_clamped = jnp.minimum(need, d["slice_ingress"])
@@ -867,6 +862,29 @@ def _stage_accept(st: SimState, wires, c):
                                         burst * REG_SCALE, 0)
     ing_used = st.ing_used + jnp.sum(
         jnp.where(can[:, None], need, 0), axis=0)
+    reg_held = st.reg_held + (room & ing_ok & ~tokens_ok)
+    return can, reg_tokens, ing_used, reg_held
+
+
+@register_stage("accept")
+def _stage_accept(st: SimState, wires, c):
+    """Command acceptance, one per port per cycle: outstanding credits,
+    split-buffer credits, W-data-bus pacing, the best-effort token-bucket
+    regulator, and the inter-slice router's admission gate (a burst with
+    remote beats needs free ingress credits on every destination slice)."""
+    N = c["N"]
+    now = st.now
+    ar = c["ar"]
+    nt = st.next_txn
+    has_txn = nt < N
+    nt_c = jnp.minimum(nt, N - 1)
+    burst = widen(c["tx_burst"][ar, nt_c])
+    is_w = widen(c["tx_write"][ar, nt_c])
+    ready = c["tx_start"][ar, nt_c] <= now
+    dirn = is_w  # 0 = read, 1 = write (AXI channels are independent)
+    need = widen(c["tx_ing"][ar, nt_c])                     # [X, NSL]
+    can, reg_tokens, ing_used, reg_held = _admit(
+        st, c, has_txn & (burst > 0) & ready, burst, is_w, need)
     accept = jnp.where(can[:, None] & (c["txn_ids"] == nt_c[:, None]),
                        now, st.accept_cycle)
     next_txn = nt + can.astype(jnp.int32)
@@ -878,7 +896,7 @@ def _stage_accept(st: SimState, wires, c):
     st = st.replace(next_txn=next_txn, outstanding=outstanding,
                     credits=credits, fwd_free=fwd_free,
                     reg_tokens=reg_tokens, ing_used=ing_used,
-                    accept_cycle=accept)
+                    accept_cycle=accept, reg_held=reg_held)
     return st, dict(wires, accept=dict(can=can, burst=burst, is_w=is_w,
                                        nt_c=nt_c))
 
@@ -982,7 +1000,10 @@ def _stage_bank_arbitrate(st: SimState, wires, c):
     and :func:`_arbiter_by_lookup` on a CPU, chosen when the program is
     lowered for its platform (``SimParams.arbiter`` picks the jax reduction
     or the Pallas kernel in either); every piece of bookkeeping then
-    derives from the [NB] winner view."""
+    derives from the [NB] winner view.  ``aged_grants`` counts, on each
+    ring slot, the grants to a beat that aging had lifted above its
+    master's level; it is added in the slot update the stage makes anyway,
+    so no reduction or gather joins the cycle for it."""
     X, P, S = c["X"], c["P"], c["S"]
     d = c["d"]
     now = st.now
@@ -1021,10 +1042,15 @@ def _stage_bank_arbitrate(st: SimState, wires, c):
                       dtype=jnp.int32)
     credits = st.credits + jnp.stack(
         [freed_r, freed_w], axis=1).astype(st.credits.dtype)
+    # grants to a beat that aging had promoted past its master's level
     st = st.replace(bank_free=bank_free, bank_rr=bank_rr,
                     sl_flags=pack_slot_flags(
                         jnp.where(granted, SLOT_GRANTED, phase), write),
-                    sl_ready=sl_ready, credits=credits)
+                    sl_ready=sl_ready, credits=credits,
+                    # ``level < slot_prio``, read off the wait: a second
+                    # reader of ``level`` keeps XLA from fusing the key
+                    aged_grants=st.aged_grants + (
+                        granted & (now - st.sl_arrive >= c["aged_after"])))
     arb = dict(has_win=has_win, wmaster=wmaster, wwrite=wwrite,
                whops=widen(st.sl_hops).reshape(S)[winc],
                wtxn=widen(st.sl_txn).reshape(S)[winc])
@@ -1155,10 +1181,10 @@ def _stage_accept_sched(st: SimState, wires, c):
     ``accept``, but the candidate burst's beat→(bank, hops, ingress-need)
     routing is computed on the fly from its address (``bank_of_dev``) instead
     of gathered from dense precomputed tables, and the accepted command is
-    allocated a slot in the in-flight table.  Decision-for-decision identical
-    to ``accept`` (golden-pinned via ``collect="exact"``)."""
+    allocated a slot in the in-flight table.  The gates are ``accept``'s own
+    (:func:`_admit`), so decisions are identical (golden-pinned via
+    ``collect="exact"``)."""
     N, NSL = c["N"], c["NSL"]
-    d = c["d"]
     now = st.now
     ar = c["ar"]
     nt = st.next_txn
@@ -1182,30 +1208,8 @@ def _stage_accept_sched(st: SimState, wires, c):
         remote[:, :, None] & (tgt[:, :, None]
                               == jnp.arange(NSL)[None, None, :]),
         axis=1).astype(jnp.int32)                          # [X, NSL]
-    # gates identical to ``accept`` (see there for the regulator/router
-    # debt-not-deadlock reasoning)
-    reg_gate = c["regulated"] & (d["reg_rate"] > 0)
-    reg_tokens = jnp.minimum(st.reg_tokens + d["reg_rate"],
-                             d["reg_burst"] * REG_SCALE)
-    reg_need = jnp.minimum(burst, d["reg_burst"]) * REG_SCALE
-    pre_can = (has_txn & (burst > 0) & ready
-               & (st.outstanding[ar, dirn] < d["outstanding"])
-               & (st.credits[ar, dirn] >= burst)
-               & ((is_w == 0) | (st.fwd_free <= now))
-               & (~reg_gate | (reg_tokens >= reg_need)))
-    need_cand = jnp.where(pre_can[:, None], need, 0)
-    prior = jnp.cumsum(need_cand, axis=0) - need_cand
-    need_clamped = jnp.minimum(need, d["slice_ingress"])
-    ing_ok = jnp.all(
-        (d["slice_ingress"] == 0) | (need_clamped == 0)
-        | (st.ing_used[None, :] + prior + need_clamped
-           <= d["slice_ingress"]),
-        axis=1)
-    can = pre_can & ing_ok
-    reg_tokens = reg_tokens - jnp.where(can & reg_gate,
-                                        burst * REG_SCALE, 0)
-    ing_used = st.ing_used + jnp.sum(
-        jnp.where(can[:, None], need, 0), axis=0)
+    can, reg_tokens, ing_used, reg_held = _admit(
+        st, c, has_txn & (burst > 0) & ready, burst, is_w, need)
     # in-flight table allocation: the credit gate caps live commands at
     # 2×outstanding - 1 < F, so a free slot (remaining == 0) always exists
     idx = jnp.argmax(widen(st.ift_remaining) == 0, axis=1).astype(jnp.int32)
@@ -1221,7 +1225,7 @@ def _stage_accept_sched(st: SimState, wires, c):
         credits=st.credits.at[ar, dirn].add(
             (-jnp.where(can, burst, 0)).astype(st.credits.dtype)),
         fwd_free=jnp.where(can & (is_w > 0), now + burst, st.fwd_free),
-        reg_tokens=reg_tokens, ing_used=ing_used,
+        reg_tokens=reg_tokens, ing_used=ing_used, reg_held=reg_held,
         ift_write=put(st.ift_write, is_w),
         ift_burst=put(st.ift_burst, burst),
         ift_remaining=put(st.ift_remaining, burst),
@@ -1460,6 +1464,16 @@ def _run_cycles(state: SimState, cycle, ctx, prm: SimParams, *,
                                        jnp.int32(MC), state.now))
 
 
+def _aged_after(prio, d, age_cap: int):
+    """Per port, the wait (cycles at the bank) from which aging has lifted a
+    beat above its master's level: ``level < prio`` exactly when ``prio >
+    0`` and ``age // qos_aging >= 1``, with the age capped at ``age_cap``.
+    ``INF32`` where aging never lifts it.  [X, 1]"""
+    qa = d["qos_aging"]
+    lifts = (prio > 0) & (qa > 0) & (qa <= age_cap)
+    return jnp.where(lifts, qa, INF32)[:, None]
+
+
 def _dense_setup(tx_write, tx_burst, tx_banks, tx_hops, tx_ing, tx_start,
                  tx_prio, dyn, prm: SimParams):
     """Cycle-0 state + stage context for the dense pipeline (shared by the
@@ -1486,6 +1500,7 @@ def _dense_setup(tx_write, tx_burst, tx_banks, tx_hops, tx_ing, tx_start,
         master_col=ar[:, None],
         flat_ids=ar[:, None] * P + pos[None, :],             # [X, P]
         slot_prio=tx_prio[:, None],                          # [X, 1]
+        aged_after=_aged_after(tx_prio, d, _age_cap(prm, X)),  # [X, 1]
         regulated=tx_prio >= REGULATED_PRIO,                 # [X]
         n_events=_port_event_counts(tx_burst, N),            # [X]
         tx_write=tx_write, tx_burst=tx_burst, tx_banks=tx_banks,
@@ -1548,6 +1563,7 @@ def _sched_setup(tx_write, tx_burst, tx_addr, tx_start, tx_prio, tx_class,
         master_col=ar[:, None],
         flat_ids=ar[:, None] * P + pos[None, :],
         slot_prio=tx_prio[:, None],
+        aged_after=_aged_after(tx_prio, d, _age_cap(prm, X)),
         regulated=tx_prio >= REGULATED_PRIO,
         n_events=_port_event_counts(tx_burst, N),
         beat_off=jnp.arange(prm.max_burst, dtype=jnp.int32),
@@ -1625,6 +1641,8 @@ def _stream_metrics(st: SimState, burst, is_w,
         "effective_cycles": jnp.where(st.drained_at >= 0, st.drained_at,
                                       st.now),
         "skipped_cycles": st.skipped,
+        "reg_held": jnp.sum(st.reg_held),
+        "aged_grants": jnp.sum(st.aged_grants),
         "slice_beats": st.slice_beats,
         "remote_beats": st.remote_beats,
         "remote_beat_fraction": jnp.where(
@@ -1706,6 +1724,10 @@ def _metrics(st: SimState, burst, is_w, prm: SimParams) -> Dict[str, jnp.ndarray
         "effective_cycles": jnp.where(st.drained_at >= 0, st.drained_at,
                                       st.now),
         "skipped_cycles": st.skipped,
+        # QoS mechanisms at work: port-cycles a regulated port's due
+        # command waited for tokens alone, and bank grants aging decided
+        "reg_held": jnp.sum(st.reg_held),
+        "aged_grants": jnp.sum(st.aged_grants),
         "complete_cycle": st.complete_cycle,
         "accept_cycle": st.accept_cycle,
         # multi-slice fabric view: beats each slice's banks served, and how

@@ -35,6 +35,10 @@ complete_cycle     int32       completion timestamp per transaction [X, N]
 beats_done         int32       read beats returned per port [X]
 drained_at         int32       cycle the run went quiescent, -1 if never ()
 skipped            int32       idle cycles jumped by the time skip ()
+reg_held           int32       cycles the port's due command waited for
+                               regulator tokens alone [X]
+aged_grants        int32       grants to a beat aging had lifted above its
+                               master's level, per ring slot [X, P]
 =================  ==========  =============================================
 
 Schedule-pipeline extension (``init_state(F=..., ...)``; every array below is
@@ -185,6 +189,9 @@ class SimState:
     # drain bookkeeping (early-exit driver + time skip; always maintained)
     drained_at: jnp.ndarray
     skipped: jnp.ndarray
+    # QoS mechanism counters (regulator holds, aging-promoted grants)
+    reg_held: jnp.ndarray
+    aged_grants: jnp.ndarray
 
     def replace(self, **updates) -> "SimState":
         """Functional field update (the stage functions' write path)."""
@@ -267,4 +274,6 @@ def init_state(*, X: int, N: int, P: int, NB: int, NSL: int,
         dl_miss=jnp.zeros((NC,), jnp.int32),
         drained_at=jnp.int32(-1),
         skipped=jnp.int32(0),
+        reg_held=jnp.zeros((X,), jnp.int32),
+        aged_grants=jnp.zeros((X, P), jnp.int32),
     )

@@ -15,8 +15,10 @@ back, so either representation runs on either pipeline.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 from typing import List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.core.address import MemoryGeometry
@@ -71,6 +73,7 @@ class EventSchedule:
                      np.asarray(self.prio, np.int32))
 
 
+@partial(jax.profiler.annotate_function, name="repro.schedule")
 def compile_schedule(trace: Trace, *,
                      classes: Optional[Sequence[int]] = None,
                      deadlines: Optional[Sequence[Optional[int]]] = None
@@ -79,7 +82,8 @@ def compile_schedule(trace: Trace, *,
 
     ``classes`` are per-master class indices (``QOS_CLASSES`` order from the
     scenario layer; default everything ``UNCLASSIFIED``); ``deadlines`` are
-    per-master completion bounds in cycles (``None`` entries → −1)."""
+    per-master completion bounds in cycles (``None`` entries → −1).  Runs
+    under the host span ``repro.schedule`` on the profiler's clock."""
     iw = np.asarray(trace.is_write)
     b = np.asarray(trace.burst)
     X = trace.num_masters

@@ -12,7 +12,9 @@ from repro.scenarios.spec import (CompiledScenario, MasterSpec, Scenario,
                                   SyntheticSource, TrafficSource,
                                   QOS_CLASSES, QOS_PRIORITY, compile_scenario)
 from repro.scenarios.generators import GENERATORS
-from repro.scenarios.library import (highway_pilot, parking_surround,
+from repro.scenarios.library import (adas_camera_suite_qos,
+                                     camera_line_master, highway_pilot,
+                                     parking_surround,
                                      preset_scenarios, qos_isolation,
                                      sensor_stress, slice_scaling,
                                      urban_perception)
@@ -36,6 +38,7 @@ __all__ = [
     "GENERATORS", "DEPRECATED_METRIC_KEYS", "MetricAliasDict", "SweepPoint",
     "SweepResult", "run_sweep", "summarize_compiled", "summarize_point",
     "ServingSource", "serving_scenario", "record_serving_run",
+    "adas_camera_suite_qos", "camera_line_master",
     "highway_pilot", "parking_surround", "preset_scenarios", "qos_isolation",
     "sensor_stress", "slice_scaling", "urban_perception",
     "FuzzCase", "FuzzConfig", "FuzzOutcome", "case_from_json", "case_to_json",

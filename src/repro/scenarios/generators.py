@@ -82,6 +82,31 @@ def camera_frame_dma(lo: int, hi: int, *, txns: int, rate: float,
     return _finalize(iw, b, a, s, lo, hi, txns)
 
 
+def camera_line_cadence(width_px: int, bytes_per_px: int, fps: float,
+                        lines_per_frame: int, clock_hz: float) -> Dict:
+    """A sensor's line DMA on the fabric clock, as :func:`camera_frame_dma`
+    knobs: each generator "frame" is one sensor line of ``width_px *
+    bytes_per_px`` bytes, written in 16-beat bursts once a line time
+    (``clock_hz / (fps * lines_per_frame)`` cycles, blanking lines
+    included, rounded to a whole cycle).  Returns the generator ``params``,
+    the ``rate`` that makes its period exactly one line time, and that line
+    time as the ``deadline``: the next line overwrites the buffer."""
+    line_bytes = int(width_px) * int(bytes_per_px)
+    if line_bytes % (16 * 32):                          # 256-bit beats
+        raise ValueError(f"a {line_bytes}-byte line is not a whole number of "
+                         "16-beat bursts of 32 bytes")
+    line_beats = line_bytes // 32
+    line_cycles = int(round(clock_hz / (fps * lines_per_frame)))
+    if line_cycles < line_beats:
+        raise ValueError(f"a {line_beats}-beat line cannot be written in "
+                         f"{line_cycles} cycles")
+    rate = line_beats / line_cycles
+    while np.ceil(line_beats / rate) > line_cycles:     # float rounding
+        rate = float(np.nextafter(rate, 1.0))
+    return {"params": {"line_beats": line_beats, "frame_lines": 1},
+            "rate": rate, "deadline": line_cycles}
+
+
 def radar_chirp_bursts(lo: int, hi: int, *, txns: int, rate: float,
                        seed: int, params: Dict) -> TraceRow:
     """Radar chirp cadence: every PRI a tight burst of ADC sample writes

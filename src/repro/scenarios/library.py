@@ -2,13 +2,31 @@
 
 Each preset returns a fresh :class:`Scenario`; tweak via the ``txns``
 argument (transactions per master, the knob that trades fidelity for sim
-time).  Mixes follow the embedded-ADAS platform surveys: redundant cameras +
-Radar + Lidar feeding an AI accelerator, with CPU housekeeping underneath.
+time).
+
+``urban_perception``, ``highway_pilot``, ``parking_surround``,
+``sensor_stress``, ``qos_isolation`` and ``slice_scaling`` are this repo's
+own mixes, shaped after the embedded-ADAS platform surveys (redundant
+cameras + Radar + Lidar feeding an AI accelerator, with CPU housekeeping
+underneath) but with no published SoC behind their counts, rates or duty
+cycles.  ``adas_camera_suite_qos`` is sourced: a shipped ADAS computer's
+camera suite at its sensors' line cadence (:func:`camera_line_master`),
+beside NPU and CPU masters, on the paper's prototype fabric.
 """
 from __future__ import annotations
 
 from repro.core.address import MemoryGeometry, master_home_slices
+from repro.scenarios.generators import camera_line_cadence
 from repro.scenarios.spec import MasterSpec, Scenario
+
+#: a Tesla FSD computer (HW3) camera: 1280x960 at 36 frames/s, as widely
+#: reported for its eight cameras (Talpes et al., IEEE Micro 40(2), 2020);
+#: RAW12 in 16-bit containers and 1,000 lines a frame with blanking are
+#: assumed
+HW3_CAMERA = {"width_px": 1280, "bytes_per_px": 2, "fps": 36.0,
+              "lines_per_frame": 1000}
+#: the fabric clock the prototype's cycle counts are read against (assumed)
+FABRIC_CLOCK_HZ = 1e9
 
 
 def urban_perception(txns: int = 256, geom: MemoryGeometry = MemoryGeometry()
@@ -130,6 +148,41 @@ def slice_scaling(num_slices: int = 2, txns: int = 256, *,
     return Scenario(name, masters, geom,
                     f"{num_slices}-slice fabric, per-slice Radar+NPU groups, "
                     f"{'remote' if remote else 'slice-local'} placement")
+
+
+def camera_line_master(width_px: int, bytes_per_px: int, fps: float,
+                       lines_per_frame: int, clock_hz: float, *,
+                       seed: int) -> MasterSpec:
+    """A safety camera's line-DMA master from its sensor figures: one line
+    at the sensor's own free-running phase, every burst due within one line
+    time (see :func:`~repro.scenarios.generators.camera_line_cadence`)."""
+    cad = camera_line_cadence(width_px, bytes_per_px, fps, lines_per_frame,
+                              clock_hz)
+    return MasterSpec("camera", qos="safety", rate=cad["rate"],
+                      txns=cad["params"]["line_beats"] // 16, seed=seed,
+                      params=cad["params"], deadline=cad["deadline"])
+
+
+def adas_camera_suite_qos(npu_txns: int = 2000, cpu_txns: int = 2000, *,
+                          camera: dict = HW3_CAMERA) -> Scenario:
+    """Mixed-criticality ADAS deployment (Tesla FSD computer, HW3): eight
+    camera line-DMA masters (safety, each writing one sensor line due
+    within its line time) beside six NPU masters at full injection
+    (realtime) and two CPU-cluster masters (best effort) that the
+    regulator holds — at ``SimParams(reg_rate=64, reg_burst=16)``, a
+    quarter beat a cycle.  One line period of a frame: the NPU and CPU
+    streams run until the last line has landed."""
+    masters = (
+        [camera_line_master(**camera, clock_hz=FABRIC_CLOCK_HZ, seed=s)
+         for s in range(8)] +
+        [MasterSpec("npu", qos="realtime", rate=1.0, txns=npu_txns,
+                    seed=10 + s) for s in range(6)] +
+        [MasterSpec("cpu", qos="besteffort", rate=1.0, txns=cpu_txns,
+                    seed=20 + s) for s in range(2)]
+    )
+    return Scenario("adas_camera_suite_qos", masters, MemoryGeometry(),
+                    "8 camera line DMAs with deadlines + 6 full-injection "
+                    "NPUs + 2 regulated CPU clusters")
 
 
 def preset_scenarios(txns: int = 256):

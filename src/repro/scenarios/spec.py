@@ -38,6 +38,7 @@ from dataclasses import dataclass, field
 from typing import (TYPE_CHECKING, Dict, List, Optional, Protocol, Sequence,
                     Tuple, Union, runtime_checkable)
 
+import jax
 import numpy as np
 
 from repro.core.address import MemoryGeometry, master_home_slices
@@ -197,23 +198,26 @@ class Scenario:
             claimed.append((i, m.region))
 
     def compile(self) -> "CompiledScenario":
-        """Lower this scenario to a padded, beat-aligned ``Trace``."""
-        self.validate()
-        regions = resolve_regions(self)
-        rows_iw, rows_b, rows_a, rows_s = [], [], [], []
-        for i, (m, (lo, hi)) in enumerate(zip(self.masters, regions)):
-            iw, b, a, s = m.source().emit(lo, hi, txns=m.txns, rate=m.rate,
-                                          seed=m.seed + 7919 * i,
-                                          params=m.params)
-            rows_iw.append(iw)
-            rows_b.append(b)
-            rows_a.append(a)
-            rows_s.append(s)
-        n = max(len(r) for r in rows_iw)
-        prios = [m.effective_priority() for m in self.masters]
-        trace = Trace(pad_rows(rows_iw, n), pad_rows(rows_b, n),
-                      pad_rows(rows_a, n), pad_rows(rows_s, n),
-                      np.asarray(prios, np.int32))
+        """Lower this scenario to a padded, beat-aligned ``Trace``.
+
+        Host spans on the profiler's clock: ``repro.scenario`` around the
+        whole, and in it ``repro.generate`` (the masters' traffic sources)
+        then ``repro.schedule`` (packing the rows into the trace)."""
+        with jax.profiler.TraceAnnotation("repro.scenario"):
+            self.validate()
+            regions = resolve_regions(self)
+            with jax.profiler.TraceAnnotation("repro.generate"):
+                rows = [m.source().emit(lo, hi, txns=m.txns, rate=m.rate,
+                                        seed=m.seed + 7919 * i,
+                                        params=m.params)
+                        for i, (m, (lo, hi)) in enumerate(
+                            zip(self.masters, regions))]
+            with jax.profiler.TraceAnnotation("repro.schedule"):
+                n = max(len(r[0]) for r in rows)
+                prios = [m.effective_priority() for m in self.masters]
+                trace = Trace(*(pad_rows([r[k] for r in rows], n)
+                                for k in range(4)),
+                              np.asarray(prios, np.int32))
         return CompiledScenario(self, trace, regions,
                                 [m.qos for m in self.masters], prios,
                                 [m.deadline for m in self.masters],

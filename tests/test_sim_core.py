@@ -195,7 +195,8 @@ def _lookup_arbitrate(st, wires, c):
         sl_ready=jnp.where(granted, now + occ + d["bank_latency"]
                            + d["hop_latency"] * widen(st.sl_hops),
                            st.sl_ready),
-        credits=st.credits + freed.astype(st.credits.dtype))
+        credits=st.credits + freed.astype(st.credits.dtype),
+        aged_grants=st.aged_grants + (granted & (level < c["slot_prio"])))
     arb = dict(has_win=has_win, wmaster=wmaster, wwrite=wwrite,
                whops=widen(st.sl_hops).reshape(S)[winc],
                wtxn=widen(st.sl_txn).reshape(S)[winc])
@@ -406,7 +407,7 @@ def test_init_state_narrow_dtypes():
     # and it is a pytree the scan can carry: every field is a leaf, and the
     # schedule/streaming extensions are zero-size on the dense path
     leaves = jax.tree_util.tree_leaves(st)
-    assert len(leaves) == len(dataclasses.fields(SimState)) == 46
+    assert len(leaves) == len(dataclasses.fields(SimState)) == 48
     assert st.ift_write.shape == (4, 0) and st.pt_count.shape == (0, 2)
 
 
