@@ -866,6 +866,16 @@ def _admit(st: SimState, c, due, burst, is_w, need):
     return can, reg_tokens, ing_used, reg_held
 
 
+def _charge(st: SimState, can, is_w, burst):
+    """An accepted command's outstanding count and split-buffer credits on
+    its AXI channel (column 0 read, 1 write), as a dense [X, 2] select: one
+    command a port, so no scatter."""
+    on = can[:, None] & (is_w[:, None] == jnp.arange(2))
+    return (st.outstanding + on.astype(st.outstanding.dtype),
+            st.credits - jnp.where(on, burst[:, None], 0).astype(
+                st.credits.dtype))
+
+
 @register_stage("accept")
 def _stage_accept(st: SimState, wires, c):
     """Command acceptance, one per port per cycle: outstanding credits,
@@ -881,17 +891,13 @@ def _stage_accept(st: SimState, wires, c):
     burst = widen(c["tx_burst"][ar, nt_c])
     is_w = widen(c["tx_write"][ar, nt_c])
     ready = c["tx_start"][ar, nt_c] <= now
-    dirn = is_w  # 0 = read, 1 = write (AXI channels are independent)
     need = widen(c["tx_ing"][ar, nt_c])                     # [X, NSL]
     can, reg_tokens, ing_used, reg_held = _admit(
         st, c, has_txn & (burst > 0) & ready, burst, is_w, need)
     accept = jnp.where(can[:, None] & (c["txn_ids"] == nt_c[:, None]),
                        now, st.accept_cycle)
     next_txn = nt + can.astype(jnp.int32)
-    outstanding = st.outstanding.at[ar, dirn].add(
-        can.astype(st.outstanding.dtype))
-    credits = st.credits.at[ar, dirn].add(
-        (-jnp.where(can, burst, 0)).astype(st.credits.dtype))
+    outstanding, credits = _charge(st, can, is_w, burst)
     fwd_free = jnp.where(can & (is_w > 0), now + burst, st.fwd_free)
     st = st.replace(next_txn=next_txn, outstanding=outstanding,
                     credits=credits, fwd_free=fwd_free,
@@ -899,6 +905,22 @@ def _stage_accept(st: SimState, wires, c):
                     accept_cycle=accept, reg_held=reg_held)
     return st, dict(wires, accept=dict(can=can, burst=burst, is_w=is_w,
                                        nt_c=nt_c))
+
+
+def _select_beat(row, offc):
+    """Each ring slot's beat value ``row[x, offc[x, p]]`` [X, P], picked
+    from its port's accepted-burst ``row`` [X, mb] by a dense one-hot:
+    every slot's offset is compared with each beat index, the hit selected
+    and summed over the beat axis.  Laid out [X, mb, P] with the ring
+    slots on the lane axis, so the mask fuses into its reduction; no
+    gather or scatter over the slots.  Exactly one beat index hits each
+    slot (``offc`` lies in [0, mb)), so the sum is that beat's value.  On
+    a CPU it costs what the per-slot lookups did, so one lowering serves
+    every platform."""
+    beats = jnp.arange(row.shape[-1], dtype=offc.dtype)[None, :, None]
+    hit = offc[:, None, :] == beats                           # [X, mb, P]
+    return jnp.sum(jnp.where(hit, row[:, :, None], 0), axis=1,
+                   dtype=jnp.int32).astype(row.dtype)
 
 
 @register_stage("dispatch")
@@ -912,7 +934,10 @@ def _stage_dispatch(st: SimState, wires, c):
     Slot-ring math is dense over the ``[X, P]`` layout: slot ``p`` of port
     ``x`` would hold beat ``(p - beats_issued[x]) mod P`` of the burst; a
     slot whose beat index is inside the accepted burst is (re)written —
-    bit-for-bit the scatter the pre-refactor core did, with no scatter."""
+    bit-for-bit the scatter the pre-refactor core did, with no scatter.
+    The accepted transaction's bank and hop rows are read once a port (an
+    [X]-index lookup) and each slot's beat is picked from them by
+    :func:`_select_beat`: no per-slot gather either."""
     prm, d = c["prm"], c["d"]
     acc = wires["accept"]
     now = st.now
@@ -922,8 +947,8 @@ def _stage_dispatch(st: SimState, wires, c):
     off = (c["pos"][None, :] - st.beats_issued[:, None]) % c["P"]  # [X, P]
     wr = can[:, None] & (off < burst[:, None])
     offc = jnp.minimum(off, prm.max_burst - 1)
-    bank_new = c["tx_banks"][ar[:, None], nt_c[:, None], offc]
-    hops_new = c["tx_hops"][ar[:, None], nt_c[:, None], offc]
+    bank_new = _select_beat(c["tx_banks"][ar, nt_c], offc)   # [X, P]
+    hops_new = _select_beat(c["tx_hops"][ar, nt_c], offc)
     pace = jnp.where(is_w[:, None] > 0, off, off // prm.expand_rate)
     arrive = now + d["cmd_latency"] + pace + d["hop_latency"] * widen(hops_new)
     phase, write = unpack_slot_flags(st.sl_flags)
@@ -1193,7 +1218,6 @@ def _stage_accept_sched(st: SimState, wires, c):
     burst = widen(c["tx_burst"][ar, nt_c])
     is_w = widen(c["tx_write"][ar, nt_c])
     ready = c["tx_start"][ar, nt_c] <= now
-    dirn = is_w
     # in-scan beat routing for the candidate burst only ([X, max_burst] —
     # nothing sized by the schedule length)
     off = c["beat_off"][None, :]                           # [1, mb]
@@ -1212,18 +1236,19 @@ def _stage_accept_sched(st: SimState, wires, c):
         st, c, has_txn & (burst > 0) & ready, burst, is_w, need)
     # in-flight table allocation: the credit gate caps live commands at
     # 2×outstanding - 1 < F, so a free slot (remaining == 0) always exists
-    idx = jnp.argmax(widen(st.ift_remaining) == 0, axis=1).astype(jnp.int32)
+    free = widen(st.ift_remaining) == 0                    # [X, F]
+    idx = jnp.argmax(free, axis=1).astype(jnp.int32)
+    # the accepted command's table entry, as a dense [X, F] select
+    slot = can[:, None] & (jnp.arange(free.shape[1]) == idx[:, None])
 
     def put(tbl, val):
-        keep = widen(tbl[ar, idx])
-        return tbl.at[ar, idx].set(jnp.where(can, val, keep).astype(tbl.dtype))
+        return jnp.where(slot, jnp.expand_dims(val, -1).astype(tbl.dtype),
+                         tbl)
 
+    outstanding, credits = _charge(st, can, is_w, burst)
     upd = dict(
         next_txn=nt + can.astype(jnp.int32),
-        outstanding=st.outstanding.at[ar, dirn].add(
-            can.astype(st.outstanding.dtype)),
-        credits=st.credits.at[ar, dirn].add(
-            (-jnp.where(can, burst, 0)).astype(st.credits.dtype)),
+        outstanding=outstanding, credits=credits,
         fwd_free=jnp.where(can & (is_w > 0), now + burst, st.fwd_free),
         reg_tokens=reg_tokens, ing_used=ing_used, reg_held=reg_held,
         ift_write=put(st.ift_write, is_w),
@@ -1234,8 +1259,9 @@ def _stage_accept_sched(st: SimState, wires, c):
         ift_txn=put(st.ift_txn, nt_c),
     )
     if c["exact"]:
-        upd["accept_cycle"] = st.accept_cycle.at[ar, nt_c].max(
-            jnp.where(can, now, -1))
+        upd["accept_cycle"] = jnp.where(
+            can[:, None] & (jnp.arange(N) == nt_c[:, None]), now,
+            st.accept_cycle)
     st = st.replace(**upd)
     return st, dict(wires, accept=dict(can=can, burst=burst, is_w=is_w,
                                        nt_c=nt_c, banks_txn=banks_txn,
@@ -1246,18 +1272,18 @@ def _stage_accept_sched(st: SimState, wires, c):
 def _stage_dispatch_sched(st: SimState, wires, c):
     """Schedule-pipeline dispatch: identical ring math to ``dispatch``, but
     the burst's per-beat banks/hops come off the accept wires (computed
-    in-scan) and slots record the in-flight-table index instead of the dense
-    transaction index."""
+    in-scan, already one [X, max_burst] row a port, picked per slot by
+    :func:`_select_beat`: no per-slot gather) and slots record
+    the in-flight-table index instead of the dense transaction index."""
     prm, d = c["prm"], c["d"]
     acc = wires["accept"]
     now = st.now
-    ar = c["ar"]
     can, burst, is_w = acc["can"], acc["burst"], acc["is_w"]
     off = (c["pos"][None, :] - st.beats_issued[:, None]) % c["P"]  # [X, P]
     wr = can[:, None] & (off < burst[:, None])
     offc = jnp.minimum(off, prm.max_burst - 1)
-    bank_new = acc["banks_txn"][ar[:, None], offc]         # [X, P] int32
-    hops_new = acc["hops_txn"][ar[:, None], offc]
+    bank_new = _select_beat(acc["banks_txn"], offc)        # [X, P] int32
+    hops_new = _select_beat(acc["hops_txn"], offc)
     pace = jnp.where(is_w[:, None] > 0, off, off // prm.expand_rate)
     arrive = now + d["cmd_latency"] + pace + d["hop_latency"] * hops_new
     phase, write = unpack_slot_flags(st.sl_flags)

@@ -318,6 +318,158 @@ def test_masked_arbitration_full_sim_bit_exact(rng, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# acceptance + dispatch: dense selects against scatters and per-slot gathers
+# ---------------------------------------------------------------------------
+
+def _slot_gather(row, offc):
+    """One lookup a ring slot into its port's burst row: the oracle for
+    ``_select_beat``."""
+    return row[jnp.arange(row.shape[0])[:, None], offc]
+
+
+def _ring_offsets(beats_issued, P, mb):
+    """Each slot's clamped beat offset, as the dispatch stages compute it."""
+    off = (jnp.arange(P)[None, :] - beats_issued[:, None]) % P
+    return jnp.minimum(off, mb - 1)
+
+
+@pytest.mark.parametrize("dtype", [jnp.int8, jnp.int16, jnp.int32])
+@pytest.mark.parametrize("P,mb", [(256, 16), (64, 16), (48, 4)])
+def test_select_beat_matches_slot_gather(P, mb, dtype, rng):
+    """Random rows at every ring phase: one port per ``beats_issued``
+    residue, so every offset, wrapped or not, is read; on one lane and
+    under vmap."""
+    from repro.core.simulator import _select_beat
+    info = jnp.iinfo(dtype)
+    rows = jnp.asarray(rng.integers(info.min, info.max, (3, P, mb),
+                                    endpoint=True), dtype)
+    offc = jnp.stack([_ring_offsets(jnp.asarray(rng.permutation(P) + k * P),
+                                    P, mb) for k in range(3)])
+    got = jax.jit(_select_beat)(rows[0], offc[0])
+    assert got.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(_slot_gather(rows[0], offc[0])))
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(jax.vmap(_select_beat))(rows, offc)),
+        np.asarray(jax.vmap(_slot_gather)(rows, offc)))
+
+
+def _scatter_accept_dispatch(st, c, sched):
+    """Acceptance + dispatch as the cycle core did them with [X]-index
+    scatters (outstanding count, credits, in-flight table, acceptance time)
+    and one lookup a ring slot for the beat's bank and hops; the admission
+    gates are the stage's own."""
+    from repro.core import simulator
+    from repro.core.state import widen
+    ar, now = c["ar"], st.now
+    accept = (simulator._stage_accept_sched if sched
+              else simulator._stage_accept)
+    new, wires = accept(st, {}, c)
+    a = wires["accept"]
+    can, burst, is_w, nt_c = a["can"], a["burst"], a["is_w"], a["nt_c"]
+    upd = dict(
+        outstanding=st.outstanding.at[ar, is_w].add(
+            can.astype(st.outstanding.dtype)),
+        credits=st.credits.at[ar, is_w].add(
+            (-jnp.where(can, burst, 0)).astype(st.credits.dtype)))
+    if sched:
+        idx = a["ift_idx"]
+
+        def put(tbl, val):
+            keep = widen(tbl[ar, idx])
+            return tbl.at[ar, idx].set(
+                jnp.where(can, val, keep).astype(tbl.dtype))
+
+        upd.update(ift_write=put(st.ift_write, is_w),
+                   ift_burst=put(st.ift_burst, burst),
+                   ift_remaining=put(st.ift_remaining, burst),
+                   ift_accept=put(st.ift_accept, now),
+                   ift_start=put(st.ift_start, c["tx_start"][ar, nt_c]),
+                   ift_txn=put(st.ift_txn, nt_c))
+        if c["exact"]:
+            upd["accept_cycle"] = st.accept_cycle.at[ar, nt_c].max(
+                jnp.where(can, now, -1))
+    dispatch = (simulator._stage_dispatch_sched if sched
+                else simulator._stage_dispatch)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(simulator, "_select_beat", _slot_gather)
+        return dispatch(new.replace(**upd), wires, c)
+
+
+def _mid_run_states(rng, sched, slices, lanes=4, X=16, P=64):
+    """States of either pipeline a few cycles into a random run, one a
+    lane, with ``beats_issued`` moved so that the lanes together put every
+    port's ring at every phase (lane k, port x: residue k * X + x)."""
+    from repro.core import simulator as sim
+    from repro.core.address import MemoryGeometry
+    from repro.core.simulator import SCHEDULE_PIPELINE
+    full = SimParams(geom=MemoryGeometry(num_slices=slices),
+                     slots_override=P, max_cycles=200,
+                     stages=SCHEDULE_PIPELINE if sched else DEFAULT_PIPELINE)
+    prm = sim._static_prm(full)
+    states = []
+    for k in range(lanes):
+        t = Trace(is_write=rng.integers(0, 2, (X, 12)),
+                  burst=rng.integers(1, prm.max_burst + 1, (X, 12)),
+                  addr=rng.integers(0, 1 << 16, (X, 12)))
+        t = sim._as_input(t, sched)
+        args = sim._to_device_args(prm, sim._host_args(t, prm, sched),
+                                   full.dyn_vector(), sched)
+        setup = sim._sched_setup if sched else sim._dense_setup
+        st, ctx = setup(*args, prm)
+        st = jax.lax.scan(sim._pipeline_cycle(prm, ctx), st, None,
+                          length=int(rng.integers(0, 6)))[0]
+        bi = k * X + np.arange(X) + P * rng.integers(0, 4, X)
+        states.append((st.replace(beats_issued=jnp.asarray(
+            bi, st.beats_issued.dtype)), ctx))
+    return states, prm
+
+
+def _assert_same_state(a, b):
+    for f in dataclasses.fields(SimState):
+        np.testing.assert_array_equal(np.asarray(getattr(a, f.name)),
+                                      np.asarray(getattr(b, f.name)),
+                                      err_msg=f.name)
+
+
+@pytest.mark.parametrize("slices", [1, 2])
+@pytest.mark.parametrize("pipeline", ["dense", "schedule"])
+def test_accept_dispatch_matches_scatters_and_slot_gathers(pipeline, slices,
+                                                          rng):
+    """Both fused acceptance + dispatch stages against their scatter and
+    per-slot-gather oracle, field for field, at every ring phase with bursts
+    of every length up to ``max_burst``: each state on its own and the
+    lanes stacked under vmap.  Two slices put non-zero hops in the ring."""
+    from repro.core.simulator import (_stage_accept_dispatch,
+                                      _stage_accept_dispatch_sched)
+    sched = pipeline == "schedule"
+    stage = _stage_accept_dispatch_sched if sched else _stage_accept_dispatch
+    states, prm = _mid_run_states(rng, sched, slices)
+    wrapped = hops = 0
+    for st, ctx in states:
+        got = jax.jit(lambda s: stage(s, {}, ctx)[0])(st)
+        want = jax.jit(lambda s: _scatter_accept_dispatch(s, ctx, sched)[0])(st)
+        _assert_same_state(got, want)
+        # the ring was (re)written across its end, with hops where remote
+        wrote = np.asarray(got.beats_issued - st.beats_issued)
+        wrapped += int(np.sum((wrote > 0) & (np.asarray(st.beats_issued)
+                                             % ctx["P"] + wrote > ctx["P"])))
+        hops += int(np.sum(np.asarray(got.sl_hops) != np.asarray(st.sl_hops)))
+    assert wrapped > 0
+    assert (hops > 0) == (slices > 1)
+    # the states' shapes agree across lanes, so they stack for vmap (the
+    # traces differ, the context arrays do not: lane 0's serves every lane
+    # for the stage and its oracle alike)
+    ctx = states[0][1]
+    batch = jax.tree_util.tree_map(lambda *a: jnp.stack(a),
+                                   *[s for s, _ in states])
+    run = lambda fn: jax.jit(jax.vmap(  # noqa: E731
+        lambda s: fn(s)[0]))(batch)
+    _assert_same_state(run(lambda s: stage(s, {}, ctx)),
+                       run(lambda s: _scatter_accept_dispatch(s, ctx, sched)))
+
+
+# ---------------------------------------------------------------------------
 # stage registry
 # ---------------------------------------------------------------------------
 

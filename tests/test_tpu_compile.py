@@ -116,22 +116,39 @@ def test_dense_core_fusions_name_every_stage_for_v5e(fig4_dense_text):
     assert {f"stage.{name}" for name in DEFAULT_PIPELINE} <= owners
 
 
-def _arbitration_lookups(text):
+@pytest.fixture(scope="module")
+def sched_core(one_chip):
+    """The schedule core's compiled text at the prototype geometry, its
+    port count and its ring slots (``urban_perception`` at 256
+    transactions a master)."""
+    sched = urban_perception(txns=256).compile().schedule()
+    prm = simulator._static_prm(SimParams(max_cycles=20_000,
+                                          stages=SCHEDULE_PIPELINE))
+    host = simulator._host_args(sched, prm, True) + (prm.dyn_vector(),)
+    args = [jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype,
+                                 sharding=one_chip) for a in host]
+    core_fn = jax.jit(partial(simulator._core_sched, prm=prm))
+    X = len(sched.burst)
+    return (core_fn.lower(*args).compile().as_text(), X,
+            X * prm.slots_per_master)
+
+
+def _stage_lookups(text, stage):
     """(op, result elements) of each gather and scatter that the compiled
-    text puts under ``stage.bank_arbitrate``.  A gather of one element per
-    index has as many result elements as indices."""
+    text puts under ``stage.<stage>``.  A gather of one element per index
+    has as many result elements as indices."""
     found = []
     for line in text.splitlines():
         m = re.search(r"= \w+\[([\d,]*)\]\S* (gather|scatter)\(", line)
-        if m and "stage.bank_arbitrate" in line:
+        if m and re.search(rf"stage\.{stage}[/\"]", line):
             dims = [int(n) for n in m.group(1).split(",") if n]
             found.append((m.group(2), int(np.prod(dims))))
     return found
 
 
 @pytest.mark.parametrize("core", ["dense", "schedule"])
-def test_bank_arbitrate_has_no_slot_lookups_for_v5e(core, one_chip,
-                                                    fig4_dense_text):
+def test_bank_arbitrate_has_no_slot_lookups_for_v5e(core, fig4_dense_text,
+                                                    sched_core):
     """On the chip the arbitration stage reaches the slots through dense
     bank masks: no scatter, and no gather with an index per ring slot —
     only the [NB]-index lookups of the winners' write, hops and txn."""
@@ -139,18 +156,29 @@ def test_bank_arbitrate_has_no_slot_lookups_for_v5e(core, one_chip,
     if core == "dense":
         text, slots = fig4_dense_text, 32 * 256
     else:
-        sched = urban_perception(txns=256).compile().schedule()
-        prm = simulator._static_prm(SimParams(max_cycles=20_000,
-                                              stages=SCHEDULE_PIPELINE))
-        host = simulator._host_args(sched, prm, True) + (prm.dyn_vector(),)
-        args = [jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype,
-                                     sharding=one_chip) for a in host]
-        core_fn = jax.jit(partial(simulator._core_sched, prm=prm))
-        text = core_fn.lower(*args).compile().as_text()
-        slots = len(sched.burst) * prm.slots_per_master
-    found = _arbitration_lookups(text)
+        text, _, slots = sched_core
+    found = _stage_lookups(text, "bank_arbitrate")
     assert found, "the stage's [NB]-index gathers are missing"
     assert all(op == "gather" and n <= NB < slots for op, n in found), found
+
+
+@pytest.mark.parametrize("core", ["dense", "schedule"])
+def test_accept_dispatch_has_no_slot_lookups_for_v5e(core, fig4_dense_text,
+                                                     sched_core):
+    """On the chip acceptance + dispatch reach the ring slots through a
+    dense one-hot over each port's burst row: no scatter, and no gather
+    with an index per ring slot — only [X]-index lookups of the ports'
+    candidate commands and, in the dense core, their [max_burst] rows."""
+    mb = SimParams().max_burst
+    if core == "dense":
+        (text, X, slots), stage = (fig4_dense_text, 32, 32 * 256), \
+            "accept_dispatch"
+    else:
+        (text, X, slots), stage = sched_core, "accept_dispatch_sched"
+    found = _stage_lookups(text, stage)
+    assert found, "the stage's [X]-index lookups are missing"
+    assert all(op == "gather" and n <= X * mb < slots
+               for op, n in found), found
 
 
 def test_vmapped_bank_arbitrate_fuses_its_masks_for_v5e(one_chip):
