@@ -32,41 +32,29 @@ the command — the router's backpressure.  With ``num_slices=1`` every beat is
 local, no credit is ever consumed, and results are bit-for-bit identical to
 the single-slice simulator (pinned by the golden regression test).
 
-Cycle core architecture (the packed-state refactor)
----------------------------------------------------
+Cycle core
+----------
 
 The scan carry is a typed :class:`repro.core.state.SimState` pytree with
-explicit narrow dtypes (bit-packed slot flags, ``int8``/``int16`` for hop
-counts, credits, and indices — see ``core/state.py`` for the field table);
-stage functions widen fields to int32 views on read and narrow on write, so
-arithmetic semantics are unchanged.  Beat slots are laid out ``[X, P]``
-(port-major), which turns the per-port return bus and dispatch ring into
-dense vector ops along the ``P`` axis; only per-bank arbitration reduces
-across ports: on the chip through dense [X, NB, P] bank masks, on a CPU
-through one flat comparator-tree call.
+narrow storage dtypes (``core/state.py`` has the field table); stages widen
+fields to int32 on read and narrow on write.  Beat slots are laid out
+``[X, P]`` (port-major), so the per-port return bus and dispatch ring are
+dense vector ops along ``P``; only per-bank arbitration reduces across
+ports: on the chip through dense [X, NB, P] bank masks, on a CPU through
+one flat comparator tree (``SimParams.arbiter``: the jax reduction, or the
+Pallas kernel of ``kernels/bank_arbiter/``; bit-exact either way).
 
-The cycle body is a *stage registry*: each stage is registered by name
-(:func:`register_stage`) with the uniform signature
-``stage(state, wires, ctx) -> (state, wires)`` — ``wires`` carries the
-intra-cycle values stages hand each other (acceptance decisions, per-bank
-grant winners, return-bus picks), ``ctx`` the static tensors and traced dyn
-scalars.  ``SimParams.stages`` selects the pipeline (default
-``DEFAULT_PIPELINE``), so router/arbiter variants are swappable by
-configuration instead of by editing ``cycle()``:
-
-  ``accept``          acceptance: credits, regulator, router admission
-  ``dispatch``        split-by-4 dispatch into beat slots (+hop delay)
-  ``bank_arbitrate``  per-bank QoS arbitration, one grant per bank
-  ``router_release``  ingress-credit release + per-slice accounting
-  ``return_bus``      read-return bus, one beat per port per cycle
-  ``retire``          transaction completion + busy-cycle accounting
-
-The per-bank comparator tree itself is a swappable backend
-(``SimParams.arbiter``): ``"jax"`` runs one masked (key, slot)
-min-reduction per bank on the chip and the two-pass ``segment_min``
-reference on a CPU, ``"pallas"`` the Pallas TPU kernel
-(``kernels/bank_arbiter/``; compiled on TPU, interpreted on CPU) —
-bit-exact either way (hypothesis-tested grant-for-grant).
+The cycle body runs registered stages (:func:`register_stage`) in the order
+``SimParams.stages`` names.  Two pipelines give bit-exact results:
+``DEFAULT_PIPELINE`` reads per-beat bank tables the host precomputes, and
+``SCHEDULE_PIPELINE`` advances packed per-master event schedules inside the
+scan, routing beats to banks on the fly and keeping per-command state in a
+fixed-width in-flight table (it alone skips idle stretches and can stream
+its statistics).  Both run through one input path (:func:`_host_input`,
+:func:`_device_input`), one compiled-program cache
+(:func:`_core_jitted_cached`) and one setup (:func:`_setup`); their own
+stages share the acceptance gates, the ring write, the completion step and
+the port-level metrics.
 
 Everything is a fixed-size jnp array and one ``lax.scan`` over cycles, so a
 whole sweep runs as a single vmapped scan: :func:`simulate_batch` evaluates a
@@ -324,8 +312,8 @@ class Trace:
 
 
 def _precompute_beats(trace: Trace, prm: SimParams):
-    """Static per-beat routing info (numpy): global bank ids, valid mask,
-    inter-slice hop counts, and per-transaction ingress-credit needs
+    """Static per-beat routing info (numpy): global bank ids, inter-slice
+    hop counts, and per-transaction ingress-credit needs
     ([X, N, num_slices] remote beats per destination slice).
 
     Hops and ingress needs derive from the *bank's* slice (``bank_id //
@@ -361,34 +349,7 @@ def _precompute_beats(trace: Trace, prm: SimParams):
     remote = valid & (hops > 0)
     ingress = np.stack([(remote & (tgt == s)).sum(axis=-1)
                         for s in range(g.num_slices)], axis=-1)
-    return (banks.astype(np.int32), valid, hops,
-            ingress.astype(np.int32))
-
-
-def _device_args(prm: SimParams, iw, b, banks, hops, ing, start, prio, dyn):
-    """Host arrays → narrow device dtypes (one choke point so the sequential
-    and batched paths cannot drift): burst/write/prio/hops int8, ingress
-    int16, banks the narrowest dtype that indexes the fabric's banks."""
-    return (jnp.asarray(iw, jnp.int8), jnp.asarray(b, jnp.int8),
-            jnp.asarray(banks, bank_dtype(prm.geom.num_banks)),
-            jnp.asarray(hops, jnp.int8), jnp.asarray(ing, jnp.int16),
-            jnp.asarray(start, jnp.int32), jnp.asarray(prio, jnp.int8),
-            jnp.asarray(dyn, jnp.int32))
-
-
-# ---------------------------------------------------------------------------
-# The cycle scan
-# ---------------------------------------------------------------------------
-
-def _as_input(trace, use_sched: bool):
-    """Normalize a Trace/EventSchedule input to what the pipeline runs on
-    (schedules compile from traces with unclassified class / no deadline;
-    dense runs of a schedule fall back to its trace view)."""
-    from repro.core.traffic import EventSchedule, compile_schedule
-    if use_sched:
-        return (trace if isinstance(trace, EventSchedule)
-                else compile_schedule(trace))
-    return trace.to_trace() if isinstance(trace, EventSchedule) else trace
+    return banks.astype(np.int32), hops, ingress.astype(np.int32)
 
 
 def _validate_schedule(sched, prm: SimParams) -> None:
@@ -416,31 +377,44 @@ def _validate_schedule(sched, prm: SimParams) -> None:
             f"({g.num_slices} slice(s))")
 
 
-def _host_args(trace, prm: SimParams, use_sched: bool) -> tuple:
-    """One point's host-side argument tuple (before device conversion)."""
-    if use_sched:
-        _validate_schedule(trace, prm)
-        return (np.asarray(trace.is_write, np.int8),
-                np.asarray(trace.burst, np.int8),
-                np.asarray(trace.addr, np.int32),
-                np.asarray(trace.start, np.int32),
-                np.asarray(trace.prio, np.int8),
-                np.asarray(trace.cls, np.int8),
-                np.asarray(trace.deadline, np.int32))
-    banks, _, hops, ing = _precompute_beats(trace, prm)
-    return (np.asarray(trace.is_write, np.int32),
-            np.asarray(trace.burst, np.int32), banks, hops, ing,
-            trace.start_or_zeros(), trace.prio_or_zeros())
+# ---------------------------------------------------------------------------
+# The input path: host preparation, then device conversion
+# ---------------------------------------------------------------------------
+
+def _host_input(trace, prm: SimParams) -> tuple:
+    """One point's core inputs on the host (``dyn`` apart), validated, from
+    a :class:`Trace` or :class:`~repro.core.traffic.EventSchedule`: packed
+    event columns for the schedule pipeline (a trace compiles to a schedule
+    with no class or deadline), precomputed per-beat bank, hop and ingress
+    tables for the dense one (a schedule runs as its trace view)."""
+    from repro.core.traffic import EventSchedule, compile_schedule
+    if prm.uses_schedule():
+        s = (trace if isinstance(trace, EventSchedule)
+             else compile_schedule(trace))
+        _validate_schedule(s, prm)
+        return (np.asarray(s.is_write, np.int8), np.asarray(s.burst, np.int8),
+                np.asarray(s.addr, np.int32), np.asarray(s.start, np.int32),
+                np.asarray(s.prio, np.int8), np.asarray(s.cls, np.int8),
+                np.asarray(s.deadline, np.int32))
+    t = trace.to_trace() if isinstance(trace, EventSchedule) else trace
+    banks, hops, ing = _precompute_beats(t, prm)
+    return (np.asarray(t.is_write, np.int32), np.asarray(t.burst, np.int32),
+            banks, hops, ing, t.start_or_zeros(), t.prio_or_zeros())
 
 
-def _to_device_args(prm: SimParams, host: tuple, dyn, use_sched: bool):
-    if use_sched:
-        iw, b, addr, start, prio, cls, dl = host
-        return (jnp.asarray(iw, jnp.int8), jnp.asarray(b, jnp.int8),
-                jnp.asarray(addr, jnp.int32), jnp.asarray(start, jnp.int32),
-                jnp.asarray(prio, jnp.int8), jnp.asarray(cls, jnp.int8),
-                jnp.asarray(dl, jnp.int32), jnp.asarray(dyn, jnp.int32))
-    return _device_args(prm, *host, dyn)
+def _device_input(prm: SimParams, host: tuple, dyn) -> tuple:
+    """Host inputs (one point's, or stacked on leading batch axes) and
+    ``dyn`` → the core's device arguments, in narrow dtypes: int8 flags,
+    bursts, hops, priorities and classes, int16 ingress, banks the
+    narrowest dtype that indexes the fabric's banks, int32 the rest."""
+    if prm.uses_schedule():
+        kinds = (jnp.int8, jnp.int8, jnp.int32, jnp.int32, jnp.int8,
+                 jnp.int8, jnp.int32)
+    else:
+        kinds = (jnp.int8, jnp.int8, bank_dtype(prm.geom.num_banks),
+                 jnp.int8, jnp.int16, jnp.int32, jnp.int8)
+    return tuple(jnp.asarray(a, k) for a, k in
+                 zip(host + (dyn,), kinds + (jnp.int32,)))
 
 
 def simulate(trace, prm: SimParams = SimParams()) -> Dict[str, np.ndarray]:
@@ -452,13 +426,10 @@ def simulate(trace, prm: SimParams = SimParams()) -> Dict[str, np.ndarray]:
     dense pipeline precomputes beat tables) and inputs are converted to
     match."""
     with jax.profiler.TraceAnnotation("repro.simulate"):
-        use_sched = prm.uses_schedule()
         with jax.profiler.TraceAnnotation("repro.prepare"):
-            t = _as_input(trace, use_sched)
-            args = _to_device_args(prm, _host_args(t, prm, use_sched),
-                                   prm.dyn_vector(), use_sched)
-        fn = _sched_jitted(prm) if use_sched else _core_jitted(prm)
-        out = fn(*args)
+            args = _device_input(prm, _host_input(trace, prm),
+                                 prm.dyn_vector())
+        out = _core_jitted_cached(_static_prm(prm), "single", False)(*args)
         with jax.profiler.TraceAnnotation("repro.fetch"):
             return jax.tree_util.tree_map(np.asarray, out)
 
@@ -475,12 +446,9 @@ def compile_simulate(trace, prm: SimParams):
     be called any number of times.  ``run.compiled`` is the compiled
     program, for callers that inspect it (``as_text()``).
     """
-    use_sched = prm.uses_schedule()
-    t = _as_input(trace, use_sched)
-    fn = _sched_jitted(prm) if use_sched else _core_jitted(prm)
-    args = _to_device_args(prm, _host_args(t, prm, use_sched),
-                           prm.dyn_vector(), use_sched)
-    compiled = fn.lower(*args).compile()
+    args = _device_input(prm, _host_input(trace, prm), prm.dyn_vector())
+    compiled = _core_jitted_cached(_static_prm(prm), "single",
+                                   False).lower(*args).compile()
 
     def run():
         out = jax.block_until_ready(compiled(*args))
@@ -600,19 +568,14 @@ def prepare_batch(traces, prms: Sequence[SimParams], *, shard: bool = True,
         raise ValueError(f"{len(traces)} traces vs {len(prms)} param points "
                          "(pass one trace to share it across all points)")
     env = batch_envelope(prms)
-    use_sched = env.uses_schedule()
-    traces = [_as_input(t, use_sched) for t in traces]
-    shape = traces[0].is_write.shape
-    for t in traces[1:]:
-        if t.is_write.shape != shape:
-            raise ValueError("all traces in a batch must share [X, N]; "
-                             f"got {t.is_write.shape} vs {shape}")
     dyn = np.stack([p.dyn_vector() for p in prms])
-    if shared:
-        targs = [np.asarray(a) for a in _host_args(traces[0], env, use_sched)]
-    else:
-        per = [_host_args(t, p, use_sched) for t, p in zip(traces, prms)]
-        targs = [np.stack([h[i] for h in per]) for i in range(len(per[0]))]
+    per = [_host_input(t, p) for t, p in zip(traces, prms)]
+    shape = per[0][0].shape
+    for h in per[1:]:
+        if h[0].shape != shape:
+            raise ValueError("all traces in a batch must share [X, N]; "
+                             f"got {h[0].shape} vs {shape}")
+    rows = [dyn] if shared else [np.stack(c) for c in zip(*per)] + [dyn]
 
     ndev = len(jax.devices()) if shard else 1
     chunked = chunk is not None and 0 < chunk < B
@@ -621,21 +584,16 @@ def prepare_batch(traces, prms: Sequence[SimParams], *, shard: bool = True,
         lead = (-(-B // C), C)
     else:
         lead = (-(-B // ndev) * ndev,)
-    batched = _pad_batch([dyn] if shared else targs + [dyn],
-                         int(np.prod(lead)) - B)
+    batched = _pad_batch(rows, int(np.prod(lead)) - B)
     batched = [a.reshape(lead + a.shape[1:]) for a in batched]
-    host = tuple(targs) if shared else tuple(batched[:-1])
-    args = list(_to_device_args(env, host, batched[-1], use_sched))
+    host = per[0] if shared else tuple(batched[:-1])
+    args = list(_device_input(env, host, batched[-1]))
     first = len(args) - 1 if shared else 0
     if ndev > 1:
         sharding = batch_sharding(lead[-1], axis=len(lead) - 1)
         args[first:] = [jax.device_put(a, sharding) for a in args[first:]]
-    if chunked:
-        fn = _chunked_jitted(env, use_sched, shared)
-    elif shared:
-        fn = _shared_batch_jitted(env, use_sched)
-    else:
-        fn = _sched_batch_jitted(env) if use_sched else _batch_jitted(env)
+    fn = _core_jitted_cached(_static_prm(env), "shared" if shared else "each",
+                             chunked)
     return PreparedBatch(fn, tuple(args), B, len(lead), first)
 
 
@@ -649,71 +607,27 @@ def _static_prm(prm: SimParams) -> SimParams:
                                **{f: 0 for f in DYN_FIELDS})
 
 
-def _core_jitted(prm: SimParams):
-    return _core_jitted_cached(_static_prm(prm))
-
-
-def _batch_jitted(prm: SimParams):
-    return _batch_jitted_cached(_static_prm(prm))
-
-
-def _sched_jitted(prm: SimParams):
-    return _sched_jitted_cached(_static_prm(prm))
-
-
-def _sched_batch_jitted(prm: SimParams):
-    return _sched_batch_jitted_cached(_static_prm(prm))
-
-
-def _shared_batch_jitted(prm: SimParams, use_sched: bool):
-    return _shared_batch_jitted_cached(_static_prm(prm), use_sched)
-
-
-def _chunked_jitted(prm: SimParams, use_sched: bool, shared: bool):
-    return _chunked_jitted_cached(_static_prm(prm), use_sched, shared)
-
-
 @lru_cache(maxsize=32)
-def _core_jitted_cached(prm: SimParams):
-    return jax.jit(partial(_core, prm=prm))
-
-
-@lru_cache(maxsize=32)
-def _batch_jitted_cached(prm: SimParams):
-    return jax.jit(jax.vmap(partial(_core, prm=prm)))
-
-
-@lru_cache(maxsize=32)
-def _sched_jitted_cached(prm: SimParams):
-    return jax.jit(partial(_core_sched, prm=prm))
-
-
-@lru_cache(maxsize=32)
-def _sched_batch_jitted_cached(prm: SimParams):
-    return jax.jit(jax.vmap(partial(_core_sched, prm=prm)))
-
-
-@lru_cache(maxsize=32)
-def _shared_batch_jitted_cached(prm: SimParams, use_sched: bool):
-    """One trace broadcast across every point: only ``dyn`` is batched."""
-    core = partial(_core_sched if use_sched else _core, prm=prm)
-    return jax.jit(jax.vmap(core, in_axes=(None,) * 7 + (0,)))
-
-
-@lru_cache(maxsize=32)
-def _chunked_jitted_cached(prm: SimParams, use_sched: bool, shared: bool):
-    """``lax.map`` over chunks of a vmapped core: peak live memory is one
-    chunk of points, not the whole grid."""
-    core = partial(_core_sched if use_sched else _core, prm=prm)
+def _core_jitted_cached(prm: SimParams, lanes: str, chunked: bool):
+    """The compiled program every entry point runs: a ``jax.jit`` of
+    ``_core`` or ``_core_sched`` (as ``prm``'s pipeline needs), keyed on
+    ``_static_prm(prm)`` and the lane layout.  ``lanes``: ``"single"`` one
+    point, ``"each"`` a vmapped batch, ``"shared"`` one trace broadcast
+    across the points (only ``dyn`` batched); ``chunked`` maps the vmapped
+    core over chunks (``lax.map``): peak memory is one chunk of points."""
+    core = partial(_core_sched if prm.uses_schedule() else _core, prm=prm)
+    if lanes == "single":
+        return jax.jit(core)
+    shared = lanes == "shared"
+    body = jax.vmap(core, in_axes=(None,) * 7 + (0,)) if shared \
+        else jax.vmap(core)
+    if not chunked:
+        return jax.jit(body)
     if shared:
-        body = jax.vmap(core, in_axes=(None,) * 7 + (0,))
-
         def fn(*args):
             targs, dyn = args[:7], args[7]        # dyn: [n_chunks, C, ...]
             return jax.lax.map(lambda dd: body(*targs, dd), dyn)
     else:
-        body = jax.vmap(core)
-
         def fn(*args):                            # each: [n_chunks, C, ...]
             return jax.lax.map(lambda aa: body(*aa), args)
     return jax.jit(fn)
@@ -730,15 +644,13 @@ def _age_cap(prm: SimParams, num_masters: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# Footprint accounting (benchmarks/sim_speed.py's live-bytes gate)
+# Footprint accounting (the scale benchmarks and the serving co-sim)
 # ---------------------------------------------------------------------------
 
 def carry_nbytes(prm: SimParams, num_masters: int, num_txns: int) -> int:
     """Bytes of ONE point's scan carry (:class:`SimState`) — what a batch or
     chunk multiplies.  Shape-only (``jax.eval_shape``), nothing allocated."""
     p = _static_prm(prm)
-    use_sched = p.uses_schedule()
-    exact = p.collect == "exact"
 
     def build():
         d = {f: jnp.int32(0) for f in DYN_FIELDS}
@@ -746,9 +658,7 @@ def carry_nbytes(prm: SimParams, num_masters: int, num_txns: int) -> int:
             X=num_masters, N=num_txns, P=p.slots_per_master,
             NB=p.geom.num_banks, NSL=p.geom.num_slices,
             tx_burst=jnp.zeros((num_masters, num_txns), jnp.int8),
-            d=d, F=p.inflight_slots if use_sched else 0,
-            NC=0 if exact else STREAM_CLASSES,
-            NQ=len(STREAM_PCTS), exact=exact)
+            d=d, **_carry_layout(p))
 
     shapes = jax.eval_shape(build)
     return int(sum(int(np.prod(leaf.shape)) * leaf.dtype.itemsize
@@ -759,10 +669,7 @@ def input_nbytes(trace, prm: SimParams) -> int:
     """Bytes of ONE point's prepared simulator inputs.  The dense path's
     precomputed [X, N, max_burst] beat tables dominate it; the schedule
     path carries only the packed event arrays."""
-    use_sched = prm.uses_schedule()
-    t = _as_input(trace, use_sched)
-    return int(sum(np.asarray(a).nbytes
-                   for a in _host_args(t, prm, use_sched))
+    return int(sum(a.nbytes for a in _host_input(trace, prm))
                + prm.dyn_vector().nbytes)
 
 
@@ -923,46 +830,53 @@ def _select_beat(row, offc):
                    dtype=jnp.int32).astype(row.dtype)
 
 
-@register_stage("dispatch")
-def _stage_dispatch(st: SimState, wires, c):
-    """Split/dispatch: fan the accepted burst's beats into the per-master
-    slot ring.  Reads expand ``expand_rate`` beats/cycle at the splitter;
-    write data is paced by the 1-beat/cycle port bus.  A remote beat's
-    arrival at its bank queue is delayed ``hop_latency`` per ring hop — the
-    inter-slice router's command-path latency.
+def _write_ring(st: SimState, c, acc, banks, hops, tag) -> SimState:
+    """Both dispatch stages: fan the accepted burst's beats (``acc``, the
+    accept wires) into the per-master slot ring.  Reads expand
+    ``expand_rate`` beats/cycle at the splitter; write data is paced by the
+    1-beat/cycle port bus; a remote beat reaches its bank queue
+    ``hop_latency`` later per ring hop (the router's command path).
 
-    Slot-ring math is dense over the ``[X, P]`` layout: slot ``p`` of port
-    ``x`` would hold beat ``(p - beats_issued[x]) mod P`` of the burst; a
-    slot whose beat index is inside the accepted burst is (re)written —
-    bit-for-bit the scatter the pre-refactor core did, with no scatter.
-    The accepted transaction's bank and hop rows are read once a port (an
-    [X]-index lookup) and each slot's beat is picked from them by
-    :func:`_select_beat`: no per-slot gather either."""
+    Dense over ``[X, P]``: slot ``p`` of port ``x`` would hold beat
+    ``(p - beats_issued[x]) mod P``; a slot whose beat is inside the burst
+    is (re)written, with no scatter.  ``banks``/``hops`` are the burst's
+    [X, max_burst] rows, or [X, N, max_burst] tables read at the accepted
+    transaction; :func:`_select_beat` picks each slot's beat from its row,
+    with no per-slot gather.  Written slots record ``tag`` [X]."""
     prm, d = c["prm"], c["d"]
-    acc = wires["accept"]
-    now = st.now
-    ar = c["ar"]
-    can, burst, is_w, nt_c = (acc["can"], acc["burst"], acc["is_w"],
-                              acc["nt_c"])
+    can, burst, is_w = acc["can"], acc["burst"], acc["is_w"]
+
+    def row(v):
+        return v[c["ar"], acc["nt_c"]] if v.ndim == 3 else v
+
     off = (c["pos"][None, :] - st.beats_issued[:, None]) % c["P"]  # [X, P]
     wr = can[:, None] & (off < burst[:, None])
     offc = jnp.minimum(off, prm.max_burst - 1)
-    bank_new = _select_beat(c["tx_banks"][ar, nt_c], offc)   # [X, P]
-    hops_new = _select_beat(c["tx_hops"][ar, nt_c], offc)
+    bank_new = _select_beat(row(banks), offc)                # [X, P]
+    hops_new = _select_beat(row(hops), offc)
     pace = jnp.where(is_w[:, None] > 0, off, off // prm.expand_rate)
-    arrive = now + d["cmd_latency"] + pace + d["hop_latency"] * widen(hops_new)
+    arrive = (st.now + d["cmd_latency"] + pace
+              + d["hop_latency"] * widen(hops_new))
     phase, write = unpack_slot_flags(st.sl_flags)
-    st = st.replace(
+    return st.replace(
         sl_flags=pack_slot_flags(jnp.where(wr, SLOT_WAITING, phase),
                                  jnp.where(wr, is_w[:, None], write)),
-        sl_bank=jnp.where(wr, bank_new, st.sl_bank),
+        sl_bank=jnp.where(wr, bank_new.astype(st.sl_bank.dtype), st.sl_bank),
         sl_arrive=jnp.where(wr, arrive, st.sl_arrive),
         sl_ready=jnp.where(wr, INF32, st.sl_ready),
-        sl_txn=jnp.where(wr, nt_c[:, None].astype(st.sl_txn.dtype),
+        sl_txn=jnp.where(wr, tag[:, None].astype(st.sl_txn.dtype),
                          st.sl_txn),
-        sl_hops=jnp.where(wr, hops_new, st.sl_hops),
+        sl_hops=jnp.where(wr, hops_new.astype(st.sl_hops.dtype), st.sl_hops),
         beats_issued=st.beats_issued + jnp.where(can, burst, 0))
-    return st, wires
+
+
+@register_stage("dispatch")
+def _stage_dispatch(st: SimState, wires, c):
+    """Dense dispatch (:func:`_write_ring`) from the precomputed beat tables;
+    slots record the transaction's index."""
+    acc = wires["accept"]
+    return _write_ring(st, c, acc, c["tx_banks"], c["tx_hops"],
+                       acc["nt_c"]), wires
 
 
 @register_stage("accept_dispatch")
@@ -1164,40 +1078,54 @@ def _port_event_counts(tx_burst, N: int):
                      jnp.argmax(zb.astype(jnp.int32), axis=1), N)
 
 
-@register_stage("retire")
-def _stage_retire(st: SimState, wires, c):
-    """Transaction completion + busy-cycle accounting: writes complete at
-    the grant of their last beat, reads at their last return-bus beat; a
-    port is busy while it has any accepted-but-incomplete transaction on
-    that AXI channel.  Advances the cycle counter.
-
-    Beat-delivery decrements come from the cycle's grant/return winners
-    ([NB]- and [X]-sized scatter-adds) instead of slot-wide segment sums —
-    a granted write decrements its transaction at grant, a returned read at
-    its return-bus pick (≤ 1 per port per cycle)."""
-    d = c["d"]
-    now = st.now
+def _count_down(rem, wires, c):
+    """Both retire stages' beat delivery: decrement the per-command beat
+    counters ``rem`` ([X, N] dense, the [X, F] in-flight table) from the
+    cycle's winners — a granted write at its grant, a returned read at its
+    return-bus pick — by [NB]- and [X]-sized scatter-adds.  Returns the
+    int32 counters and ``just_done`` (this was the command's last beat)."""
     arb, ret = wires["arb"], wires["ret"]
-    rem_before = widen(st.remaining)
+    rem_before = widen(rem)
     wdec = (arb["has_win"] & (arb["wwrite"] == 1)).astype(jnp.int32)
     remaining = rem_before.at[arb["wmaster"], arb["wtxn"]].add(-wdec)
     remaining = remaining.at[c["ar"], ret["ret_txn"]].add(
         -ret["ret_any"].astype(jnp.int32))
-    just_done = (remaining == 0) & (rem_before > 0)
-    complete = jnp.where(just_done, now + d["ret_latency"],
-                         st.complete_cycle)
-    done_r = jnp.sum(just_done & (c["tx_write"] == 0), axis=1)
-    done_w = jnp.sum(just_done & (c["tx_write"] == 1), axis=1)
+    return remaining, (remaining == 0) & (rem_before > 0)
+
+
+def _settle(st: SimState, c, field: str, remaining, done_r, done_w, *,
+            stamp: bool = False):
+    """Both retire stages' bookkeeping from each port's completions per
+    direction: outstanding counts, busy counters (busy while a command is
+    accepted but incomplete on that channel), ``remaining`` back in
+    ``field`` and the clock's advance.  Returns the completion stamp (the
+    cycle plus the return latency; with ``stamp`` only) and the updates."""
+    now = st.now
     outstanding = st.outstanding - jnp.stack(
         [done_r, done_w], axis=1).astype(st.outstanding.dtype)
     in_r = (outstanding[:, 0] > 0).astype(jnp.int32)
     in_w = (outstanding[:, 1] > 0).astype(jnp.int32)
-    st = st.replace(now=now + 1, outstanding=outstanding,
-                    remaining=remaining.astype(st.remaining.dtype),
-                    complete_cycle=complete,
-                    busy_r=st.busy_r + in_r, busy_w=st.busy_w + in_w,
-                    busy_any=st.busy_any + jnp.maximum(in_r, in_w))
-    return _latch_drained(st, c), wires
+    complete_t = now + c["d"]["ret_latency"] if stamp else None
+    return complete_t, {
+        "now": now + 1, "outstanding": outstanding,
+        field: remaining.astype(getattr(st, field).dtype),
+        "busy_r": st.busy_r + in_r, "busy_w": st.busy_w + in_w,
+        "busy_any": st.busy_any + jnp.maximum(in_r, in_w)}
+
+
+@register_stage("retire")
+def _stage_retire(st: SimState, wires, c):
+    """Transaction completion (:func:`_count_down`, :func:`_settle`): writes
+    complete at their last beat's grant, reads at its return; each is
+    stamped in the [X, N] ``complete_cycle``."""
+    remaining, just_done = _count_down(st.remaining, wires, c)
+    complete = jnp.where(just_done, st.now + c["d"]["ret_latency"],
+                         st.complete_cycle)
+    done_r = jnp.sum(just_done & (c["tx_write"] == 0), axis=1)
+    done_w = jnp.sum(just_done & (c["tx_write"] == 1), axis=1)
+    _, upd = _settle(st, c, "remaining", remaining, done_r, done_w)
+    return _latch_drained(st.replace(complete_cycle=complete, **upd),
+                          c), wires
 
 
 @register_stage("accept_sched")
@@ -1270,34 +1198,11 @@ def _stage_accept_sched(st: SimState, wires, c):
 
 @register_stage("dispatch_sched")
 def _stage_dispatch_sched(st: SimState, wires, c):
-    """Schedule-pipeline dispatch: identical ring math to ``dispatch``, but
-    the burst's per-beat banks/hops come off the accept wires (computed
-    in-scan, already one [X, max_burst] row a port, picked per slot by
-    :func:`_select_beat`: no per-slot gather) and slots record
-    the in-flight-table index instead of the dense transaction index."""
-    prm, d = c["prm"], c["d"]
+    """Schedule dispatch (:func:`_write_ring`) from the rows acceptance routed
+    in-scan; slots record the in-flight-table index."""
     acc = wires["accept"]
-    now = st.now
-    can, burst, is_w = acc["can"], acc["burst"], acc["is_w"]
-    off = (c["pos"][None, :] - st.beats_issued[:, None]) % c["P"]  # [X, P]
-    wr = can[:, None] & (off < burst[:, None])
-    offc = jnp.minimum(off, prm.max_burst - 1)
-    bank_new = _select_beat(acc["banks_txn"], offc)        # [X, P] int32
-    hops_new = _select_beat(acc["hops_txn"], offc)
-    pace = jnp.where(is_w[:, None] > 0, off, off // prm.expand_rate)
-    arrive = now + d["cmd_latency"] + pace + d["hop_latency"] * hops_new
-    phase, write = unpack_slot_flags(st.sl_flags)
-    st = st.replace(
-        sl_flags=pack_slot_flags(jnp.where(wr, SLOT_WAITING, phase),
-                                 jnp.where(wr, is_w[:, None], write)),
-        sl_bank=jnp.where(wr, bank_new.astype(st.sl_bank.dtype), st.sl_bank),
-        sl_arrive=jnp.where(wr, arrive, st.sl_arrive),
-        sl_ready=jnp.where(wr, INF32, st.sl_ready),
-        sl_txn=jnp.where(wr, acc["ift_idx"][:, None].astype(st.sl_txn.dtype),
-                         st.sl_txn),
-        sl_hops=jnp.where(wr, hops_new.astype(jnp.int8), st.sl_hops),
-        beats_issued=st.beats_issued + jnp.where(can, burst, 0))
-    return st, wires
+    return _write_ring(st, c, acc, acc["banks_txn"], acc["hops_txn"],
+                       acc["ift_idx"]), wires
 
 
 @register_stage("accept_dispatch_sched")
@@ -1319,29 +1224,13 @@ def _stage_retire_sched(st: SimState, wires, c):
     groups per (view, class, direction) for latency percentiles, and
     per-class deadline counters — so nothing in the carry scales with the
     schedule length."""
-    d = c["d"]
-    now = st.now
-    arb, ret = wires["arb"], wires["ret"]
-    rem_before = widen(st.ift_remaining)                   # [X, F]
-    wdec = (arb["has_win"] & (arb["wwrite"] == 1)).astype(jnp.int32)
-    remaining = rem_before.at[arb["wmaster"], arb["wtxn"]].add(-wdec)
-    remaining = remaining.at[c["ar"], ret["ret_txn"]].add(
-        -ret["ret_any"].astype(jnp.int32))
-    just_done = (remaining == 0) & (rem_before > 0)
+    remaining, just_done = _count_down(st.ift_remaining, wires, c)
     iw = widen(st.ift_write)
     jr = just_done & (iw == 0)
     jw = just_done & (iw == 1)
-    done_r = jnp.sum(jr, axis=1)
-    done_w = jnp.sum(jw, axis=1)
-    outstanding = st.outstanding - jnp.stack(
-        [done_r, done_w], axis=1).astype(st.outstanding.dtype)
-    in_r = (outstanding[:, 0] > 0).astype(jnp.int32)
-    in_w = (outstanding[:, 1] > 0).astype(jnp.int32)
-    complete_t = now + d["ret_latency"]
-    upd = dict(now=now + 1, outstanding=outstanding,
-               ift_remaining=remaining.astype(st.ift_remaining.dtype),
-               busy_r=st.busy_r + in_r, busy_w=st.busy_w + in_w,
-               busy_any=st.busy_any + jnp.maximum(in_r, in_w))
+    complete_t, upd = _settle(st, c, "ift_remaining", remaining,
+                              jnp.sum(jr, axis=1), jnp.sum(jw, axis=1),
+                              stamp=True)
     if c["exact"]:
         rows = jnp.broadcast_to(c["ar"][:, None], just_done.shape)
         upd["complete_cycle"] = st.complete_cycle.at[
@@ -1500,41 +1389,72 @@ def _aged_after(prio, d, age_cap: int):
     return jnp.where(lifts, qa, INF32)[:, None]
 
 
-def _dense_setup(tx_write, tx_burst, tx_banks, tx_hops, tx_ing, tx_start,
-                 tx_prio, dyn, prm: SimParams):
-    """Cycle-0 state + stage context for the dense pipeline (shared by the
-    jitted core and the drained-fixpoint property tests)."""
-    X, N = tx_write.shape
-    P = prm.slots_per_master
-    S = X * P
-    NB = prm.geom.num_banks
-    NSL = prm.geom.num_slices
+def _carry_layout(prm: SimParams) -> dict:
+    """``init_state``'s pipeline-dependent sizes: the schedule pipeline's
+    in-flight table and, streaming, the accumulators."""
+    if not prm.uses_schedule():
+        return {}
+    exact = prm.collect == "exact"
+    return dict(F=prm.inflight_slots, NC=0 if exact else STREAM_CLASSES,
+                NQ=len(STREAM_PCTS), exact=exact)
 
-    dyn = jnp.asarray(dyn, jnp.int32)
+
+#: the cores' inputs before ``dyn``, by name: dense beat tables, or the
+#: schedule's packed event columns
+_DENSE_INPUTS = ("tx_write", "tx_burst", "tx_banks", "tx_hops", "tx_ing",
+                 "tx_start", "tx_prio")
+_SCHED_INPUTS = ("tx_write", "tx_burst", "tx_addr", "tx_start", "tx_prio",
+                 "tx_class", "tx_deadline")
+
+
+def _setup(args, prm: SimParams):
+    """Cycle-0 state + stage context from a core's arguments (its inputs,
+    then ``dyn``), for either pipeline: the common context, then each
+    pipeline's own entries."""
+    sched = prm.uses_schedule()
+    tx = dict(zip(_SCHED_INPUTS if sched else _DENSE_INPUTS, args[:-1]))
+    tx_burst = tx["tx_burst"]
+    X, N = tx_burst.shape
+    P = prm.slots_per_master
+    exact = prm.collect == "exact"
+
+    dyn = jnp.asarray(args[-1], jnp.int32)
     d = {name: dyn[i] for i, name in enumerate(DYN_FIELDS)}
 
-    tx_prio = jnp.clip(widen(tx_prio), 0, PRIO_LEVELS - 1)
+    tx_prio = jnp.clip(widen(tx.pop("tx_prio")), 0, PRIO_LEVELS - 1)
     ar = jnp.arange(X, dtype=jnp.int32)
     pos = jnp.arange(P, dtype=jnp.int32)
 
-    ctx = dict(
-        X=X, N=N, P=P, S=S, NB=NB, NSL=NSL,
-        AGE_CAP=_age_cap(prm, X),
-        prm=prm, d=d,
-        ar=ar, pos=pos,
-        txn_ids=jnp.arange(N, dtype=jnp.int32)[None, :],
+    ctx = dict(X=X, N=N, P=P, S=X * P, NB=prm.geom.num_banks,
+               NSL=prm.geom.num_slices, AGE_CAP=_age_cap(prm, X),
+               prm=prm, d=d, ar=ar, pos=pos, exact=exact, NC=STREAM_CLASSES)
+    if not sched:                     # the dense acceptance's one-hot
+        ctx["txn_ids"] = jnp.arange(N, dtype=jnp.int32)[None, :]
+    ctx.update(
         master_col=ar[:, None],
         flat_ids=ar[:, None] * P + pos[None, :],             # [X, P]
         slot_prio=tx_prio[:, None],                          # [X, 1]
         aged_after=_aged_after(tx_prio, d, _age_cap(prm, X)),  # [X, 1]
         regulated=tx_prio >= REGULATED_PRIO,                 # [X]
         n_events=_port_event_counts(tx_burst, N),            # [X]
-        tx_write=tx_write, tx_burst=tx_burst, tx_banks=tx_banks,
-        tx_hops=tx_hops, tx_ing=tx_ing, tx_start=tx_start,
-    )
+        **tx)
+    if sched:                         # in-scan beat routing
+        ctx.update(
+            beat_off=jnp.arange(prm.max_burst, dtype=jnp.int32),
+            home=jnp.asarray(master_home_slices(X, prm.geom), jnp.int32),
+            banks_per_slice=prm.geom.banks_per_slice)
 
-    state = init_state(X=X, N=N, P=P, NB=NB, NSL=NSL, tx_burst=tx_burst, d=d)
+    state = init_state(X=X, N=N, P=P, NB=ctx["NB"], NSL=ctx["NSL"],
+                       tx_burst=tx_burst, d=d, **_carry_layout(prm))
     return state, ctx
+
+
+def _setup_trace(trace, prm: SimParams):
+    """Cycle-0 state + stage context for one trace or schedule, through the
+    entry points' input path (eager; for tests that step the cycle
+    body)."""
+    return _setup(_device_input(prm, _host_input(trace, prm),
+                                prm.dyn_vector()), prm)
 
 
 def _pipeline_cycle(prm: SimParams, ctx):
@@ -1553,57 +1473,24 @@ def _pipeline_cycle(prm: SimParams, ctx):
     return cycle
 
 
+def _run_core(args, prm: SimParams):
+    """Set up, run the cycle loop (time skip on the schedule pipeline) and
+    collect the metrics."""
+    state, ctx = _setup(args, prm)
+    cycle = _pipeline_cycle(prm, ctx)
+    skip = prm.time_skip and prm.uses_schedule()
+    state = _run_cycles(state, cycle, ctx, prm, skip=skip)
+    if prm.collect == "exact":
+        return _metrics(state, ctx["tx_burst"], ctx["tx_write"], prm)
+    return _stream_metrics(state, ctx["tx_burst"], ctx["tx_write"], prm)
+
+
 def _core(tx_write, tx_burst, tx_banks, tx_hops, tx_ing, tx_start, tx_prio,
           dyn, *, prm: SimParams):
-    state, ctx = _dense_setup(tx_write, tx_burst, tx_banks, tx_hops, tx_ing,
-                              tx_start, tx_prio, dyn, prm)
-    cycle = _pipeline_cycle(prm, ctx)
-    state = _run_cycles(state, cycle, ctx, prm, skip=False)
-    return _metrics(state, tx_burst, tx_write, prm)
-
-
-def _sched_setup(tx_write, tx_burst, tx_addr, tx_start, tx_prio, tx_class,
-                 tx_deadline, dyn, prm: SimParams):
-    """Cycle-0 state + stage context for the schedule pipeline (shared by
-    the jitted core and the drained-fixpoint property tests)."""
-    X, N = tx_write.shape
-    P = prm.slots_per_master
-    F = prm.inflight_slots
-    S = X * P
-    NB = prm.geom.num_banks
-    NSL = prm.geom.num_slices
-    exact = prm.collect == "exact"
-
-    dyn = jnp.asarray(dyn, jnp.int32)
-    d = {name: dyn[i] for i, name in enumerate(DYN_FIELDS)}
-
-    tx_prio = jnp.clip(widen(tx_prio), 0, PRIO_LEVELS - 1)
-    ar = jnp.arange(X, dtype=jnp.int32)
-    pos = jnp.arange(P, dtype=jnp.int32)
-
-    ctx = dict(
-        X=X, N=N, P=P, S=S, NB=NB, NSL=NSL,
-        AGE_CAP=_age_cap(prm, X),
-        prm=prm, d=d,
-        ar=ar, pos=pos,
-        master_col=ar[:, None],
-        flat_ids=ar[:, None] * P + pos[None, :],
-        slot_prio=tx_prio[:, None],
-        aged_after=_aged_after(tx_prio, d, _age_cap(prm, X)),
-        regulated=tx_prio >= REGULATED_PRIO,
-        n_events=_port_event_counts(tx_burst, N),
-        beat_off=jnp.arange(prm.max_burst, dtype=jnp.int32),
-        home=jnp.asarray(master_home_slices(X, prm.geom), jnp.int32),
-        banks_per_slice=prm.geom.banks_per_slice,
-        exact=exact, NC=STREAM_CLASSES,
-        tx_write=tx_write, tx_burst=tx_burst, tx_addr=tx_addr,
-        tx_start=tx_start, tx_class=tx_class, tx_deadline=tx_deadline,
-    )
-
-    state = init_state(X=X, N=N, P=P, NB=NB, NSL=NSL, tx_burst=tx_burst,
-                       d=d, F=F, NC=0 if exact else STREAM_CLASSES,
-                       NQ=len(STREAM_PCTS), exact=exact)
-    return state, ctx
+    """Dense-pipeline core: per-transaction beat tables precomputed on the
+    host (:func:`_precompute_beats`)."""
+    return _run_core((tx_write, tx_burst, tx_banks, tx_hops, tx_ing,
+                      tx_start, tx_prio, dyn), prm)
 
 
 def _core_sched(tx_write, tx_burst, tx_addr, tx_start, tx_prio, tx_class,
@@ -1612,13 +1499,44 @@ def _core_sched(tx_write, tx_burst, tx_addr, tx_start, tx_prio, tx_class,
     direction/burst + int32 addr/start per event, per-master class/deadline)
     advanced inside the scan — no dense [X, N, max_burst] beat tables, and
     with ``collect="stream"`` no [X, N] timestamp arrays either."""
-    state, ctx = _sched_setup(tx_write, tx_burst, tx_addr, tx_start, tx_prio,
-                              tx_class, tx_deadline, dyn, prm)
-    cycle = _pipeline_cycle(prm, ctx)
-    state = _run_cycles(state, cycle, ctx, prm, skip=prm.time_skip)
-    if prm.collect == "exact":
-        return _metrics(state, tx_burst, tx_write, prm)
-    return _stream_metrics(state, tx_burst, tx_write, prm)
+    return _run_core((tx_write, tx_burst, tx_addr, tx_start, tx_prio,
+                      tx_class, tx_deadline, dyn), prm)
+
+
+def _port_metrics(st: SimState, own) -> Dict[str, jnp.ndarray]:
+    """What both collectors report besides their own entries (``own()``,
+    built after the granted-beat count, before the rest): busy cycles,
+    beats, clock and drain, QoS counters and the multi-slice view."""
+    # granted-beat population for the remote fraction: remote_beats and
+    # slice_beats are both counted at bank grant, so the ratio stays in
+    # [0, 1] even when a run hits max_cycles without draining
+    granted_beats = jnp.sum(st.slice_beats)
+    out = own()
+    out.update({
+        "busy_cycles": st.busy_any,
+        "beats_done": st.beats_done,
+        "cycles": st.now,
+        # cycle the run went quiescent (-1: never — it hit max_cycles);
+        # effective_cycles is what the run actually had to simulate, minus
+        # any idle stretches the time skip jumped (skipped_cycles)
+        "drained_cycle": st.drained_at,
+        "effective_cycles": jnp.where(st.drained_at >= 0, st.drained_at,
+                                      st.now),
+        "skipped_cycles": st.skipped,
+        # QoS mechanisms at work: port-cycles a regulated port's due
+        # command waited for tokens alone, and bank grants aging decided
+        "reg_held": jnp.sum(st.reg_held),
+        "aged_grants": jnp.sum(st.aged_grants),
+        # multi-slice fabric view: beats each slice's banks served, and how
+        # much traffic crossed the inter-slice router (0 at num_slices=1)
+        "slice_beats": st.slice_beats,
+        "remote_beats": st.remote_beats,
+        "remote_beat_fraction": jnp.where(
+            granted_beats > 0,
+            st.remote_beats / jnp.maximum(granted_beats, 1)
+            .astype(jnp.float32), 0.0),
+    })
+    return out
 
 
 def _stream_metrics(st: SimState, burst, is_w,
@@ -1643,15 +1561,13 @@ def _stream_metrics(st: SimState, burst, is_w,
     tput_busy = jnp.where(
         count > 0, beats / jnp.maximum(busy, 1).astype(jnp.float32), 0.0)
     cnt = st.pt_count.astype(jnp.float32)
-    granted_beats = jnp.sum(st.slice_beats)
-    return {
+    return _port_metrics(st, lambda: {
         "throughput": tput[:, 2],
         "read_throughput": tput[:, 0],
         "write_throughput": tput[:, 1],
         "throughput_busy": tput_busy[:, 2],
         "read_throughput_busy": tput_busy[:, 0],
         "write_throughput_busy": tput_busy[:, 1],
-        "busy_cycles": st.busy_any,
         "read_lat_avg": jnp.where(cnt[:, 0] > 0,
                                   st.pt_lat_sum[:, 0]
                                   / jnp.maximum(cnt[:, 0], 1.0), 0.0),
@@ -1661,20 +1577,6 @@ def _stream_metrics(st: SimState, burst, is_w,
                                    / jnp.maximum(cnt[:, 1], 1.0), 0.0),
         "write_lat_max": st.pt_lat_max[:, 1],
         "all_done": jnp.sum(st.pt_count) == n_real,
-        "beats_done": st.beats_done,
-        "cycles": st.now,
-        "drained_cycle": st.drained_at,
-        "effective_cycles": jnp.where(st.drained_at >= 0, st.drained_at,
-                                      st.now),
-        "skipped_cycles": st.skipped,
-        "reg_held": jnp.sum(st.reg_held),
-        "aged_grants": jnp.sum(st.aged_grants),
-        "slice_beats": st.slice_beats,
-        "remote_beats": st.remote_beats,
-        "remote_beat_fraction": jnp.where(
-            granted_beats > 0,
-            st.remote_beats / jnp.maximum(granted_beats, 1)
-            .astype(jnp.float32), 0.0),
         # streaming accumulator state (fixed-size; see percentile.py)
         "p2_height": st.p2_height,
         "p2_npos": st.p2_npos,
@@ -1684,7 +1586,7 @@ def _stream_metrics(st: SimState, burst, is_w,
         "dl_done": st.dl_done,
         "dl_miss": st.dl_miss,
         "txns_done_port": st.pt_count,
-    }
+    })
 
 
 def _metrics(st: SimState, burst, is_w, prm: SimParams) -> Dict[str, jnp.ndarray]:
@@ -1717,18 +1619,13 @@ def _metrics(st: SimState, burst, is_w, prm: SimParams) -> Dict[str, jnp.ndarray
         cyc = jnp.maximum(busy, 1).astype(jnp.float32)
         return jnp.where(jnp.sum(sel, 1) > 0, beats / cyc, 0.0)
 
-    # granted-beat population for the remote fraction: remote_beats and
-    # slice_beats are both counted at bank grant, so the ratio stays in
-    # [0, 1] even when a run hits max_cycles without draining
-    granted_beats = jnp.sum(st.slice_beats)
-    return {
+    return _port_metrics(st, lambda: {
         "throughput": tput(real & done),
         "read_throughput": tput(r),
         "write_throughput": tput(w),
         "throughput_busy": tput_busy(real & done, st.busy_any),
         "read_throughput_busy": tput_busy(r, st.busy_r),
         "write_throughput_busy": tput_busy(w, st.busy_w),
-        "busy_cycles": st.busy_any,
         "read_lat_avg": jnp.where(jnp.sum(r, 1) > 0,
                                   jnp.sum(read_lat, 1) / n_r, 0.0),
         "read_lat_max": jnp.max(jnp.where(r, lat, 0.0), axis=1),
@@ -1741,27 +1638,6 @@ def _metrics(st: SimState, burst, is_w, prm: SimParams) -> Dict[str, jnp.ndarray
         # conservation checks work on either collection path
         "txns_done_port": jnp.stack([jnp.sum(r, axis=1), jnp.sum(w, axis=1)],
                                     axis=1).astype(jnp.int32),
-        "beats_done": st.beats_done,
-        "cycles": st.now,
-        # cycle the run went quiescent (-1: never — it hit max_cycles);
-        # effective_cycles is what the run actually had to simulate, minus
-        # any idle stretches the time skip jumped (skipped_cycles)
-        "drained_cycle": st.drained_at,
-        "effective_cycles": jnp.where(st.drained_at >= 0, st.drained_at,
-                                      st.now),
-        "skipped_cycles": st.skipped,
-        # QoS mechanisms at work: port-cycles a regulated port's due
-        # command waited for tokens alone, and bank grants aging decided
-        "reg_held": jnp.sum(st.reg_held),
-        "aged_grants": jnp.sum(st.aged_grants),
         "complete_cycle": st.complete_cycle,
         "accept_cycle": st.accept_cycle,
-        # multi-slice fabric view: beats each slice's banks served, and how
-        # much traffic crossed the inter-slice router (0 at num_slices=1)
-        "slice_beats": st.slice_beats,
-        "remote_beats": st.remote_beats,
-        "remote_beat_fraction": jnp.where(
-            granted_beats > 0,
-            st.remote_beats / jnp.maximum(granted_beats, 1)
-            .astype(jnp.float32), 0.0),
-    }
+    })

@@ -105,8 +105,8 @@ class SweepResult:
     slices: Dict[str, object] = field(default_factory=dict)
     #: sweep-level simulation rate (shared by every point of one call):
     #: wall_s, sim_cycles_per_sec (NOMINAL max_cycles / wall second, summed
-    #: over the batch — cf. benchmarks/sim_speed.py), nominal vs effective
-    #: cycles + drained_fraction (early-exit accounting), batched
+    #: over the batch), nominal vs effective cycles + drained_fraction
+    #: (early-exit accounting), batched
     sim_rate: Dict[str, object] = field(default_factory=dict)
 
     def summary(self) -> Dict[str, object]:
@@ -470,8 +470,8 @@ def _padded_schedule(compiled: CompiledScenario, padded_trace):
 def _sim_rate(prms: Sequence[SimParams], wall_s: float, batched: bool,
               per_point: Optional[Sequence[Dict[str, np.ndarray]]] = None
               ) -> Dict[str, object]:
-    """Sweep-level simulated-cycles/sec (includes JIT on a cold cache —
-    compare against ``benchmarks/sim_speed.py`` for the steady-state rate).
+    """Sweep-level simulated-cycles/sec on the host clock (includes JIT on
+    a cold cache; not a device speed record).
 
     ``sim_cycles_per_sec`` stays the *nominal* rate (``max_cycles`` summed
     over the grid) so the denominator is comparable across runs; with the

@@ -150,18 +150,8 @@ def test_drained_cycle_semantics(rng):
 FIXPOINT_EXEMPT = {"now", "reg_tokens"}
 
 
-def _setup(trace, prm):
-    use_sched = prm.uses_schedule()
-    t = sim._as_input(trace, use_sched)
-    args = sim._to_device_args(prm, sim._host_args(t, prm, use_sched),
-                               prm.dyn_vector(), use_sched)
-    if use_sched:
-        return sim._sched_setup(*args, prm)
-    return sim._dense_setup(*args, prm)
-
-
 def _assert_drained_fixpoint(trace, prm):
-    state, ctx = _setup(trace, prm)
+    state, ctx = sim._setup_trace(trace, prm)
     cycle = sim._pipeline_cycle(prm, ctx)
     st = jax.jit(lambda s: jax.lax.scan(
         cycle, s, None, length=prm.max_cycles)[0])(state)

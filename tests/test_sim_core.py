@@ -209,8 +209,7 @@ def _random_slot_state(rng, NB, arbiter, X=4, P=64, N=6):
     within and across ports), busy and free banks, and half the banks with
     no slot."""
     from repro.core.address import MemoryGeometry
-    from repro.core.simulator import (_dense_setup, _device_args,
-                                      _host_args, _static_prm)
+    from repro.core.simulator import _setup_trace, _static_prm
     prm = _static_prm(SimParams(
         geom=MemoryGeometry(num_slices=NB // 256), slots_override=P,
         max_cycles=4000, arbiter=arbiter, qos_aging=4))
@@ -218,9 +217,7 @@ def _random_slot_state(rng, NB, arbiter, X=4, P=64, N=6):
               burst=rng.integers(1, 9, (X, N)),
               addr=rng.integers(0, 3000, (X, N)),
               prio=rng.integers(0, 2, X))
-    dyn = replace(prm, qos_aging=4, hop_latency=6).dyn_vector()
-    st, ctx = _dense_setup(*_device_args(prm, *_host_args(t, prm, False),
-                                         dyn), prm)
+    st, ctx = _setup_trace(t, replace(prm, qos_aging=4, hop_latency=6))
     now = 1000
     # slots target half the banks, a quarter of them eight hot banks, so
     # many slots of one port and of several ports meet at one bank
@@ -304,8 +301,8 @@ def test_masked_arbitration_full_sim_bit_exact(rng, monkeypatch):
               prio=rng.integers(0, 4, X))
     prm = SimParams(max_cycles=2500, qos_aging=32, reg_rate=64)
     sprm = simulator._static_prm(prm)
-    args = simulator._device_args(sprm, *simulator._host_args(t, sprm, False),
-                                  prm.dyn_vector())
+    args = simulator._device_input(sprm, simulator._host_input(t, sprm),
+                                   prm.dyn_vector())
     run = lambda: jax.jit(partial(simulator._core, prm=sprm))(*args)  # noqa: E731
     want = run()
     monkeypatch.setattr(simulator, "_arbiter_by_lookup",
@@ -412,11 +409,7 @@ def _mid_run_states(rng, sched, slices, lanes=4, X=16, P=64):
         t = Trace(is_write=rng.integers(0, 2, (X, 12)),
                   burst=rng.integers(1, prm.max_burst + 1, (X, 12)),
                   addr=rng.integers(0, 1 << 16, (X, 12)))
-        t = sim._as_input(t, sched)
-        args = sim._to_device_args(prm, sim._host_args(t, prm, sched),
-                                   full.dyn_vector(), sched)
-        setup = sim._sched_setup if sched else sim._dense_setup
-        st, ctx = setup(*args, prm)
+        st, ctx = sim._setup_trace(t, full)
         st = jax.lax.scan(sim._pipeline_cycle(prm, ctx), st, None,
                           length=int(rng.integers(0, 6)))[0]
         bi = k * X + np.arange(X) + P * rng.integers(0, 4, X)

@@ -35,7 +35,8 @@ from repro.core.percentile import (P2_MIN_SAMPLES, P2_RANK_TOL_PCT,
                                    p2_update)
 from repro.core.simulator import (SCHEDULE_PIPELINE, SimParams,
                                   batch_envelope, carry_nbytes,
-                                  input_nbytes, simulate, simulate_batch)
+                                  input_nbytes, prepare_batch, simulate,
+                                  simulate_batch)
 from repro.core.traffic import (EventSchedule, compile_schedule,
                                 random_uniform, stack_traces)
 from repro.scenarios import highway_pilot
@@ -300,6 +301,40 @@ def test_batch_paths_equal_sequential(stages):
             for k in seq[0]:
                 assert np.array_equal(np.asarray(seq[i][k]),
                                       np.asarray(out[k])[i]), (tag, i, k)
+
+
+@pytest.mark.parametrize("lanes", ["single", "each", "shared", "chunked"])
+@pytest.mark.parametrize("stages", [None, SCHEDULE_PIPELINE])
+def test_dyn_points_share_one_program(stages, lanes):
+    """Points that differ only in ``DYN_FIELDS`` reuse one compiled program:
+    the compiled-program cache grows by one entry for the lane layout, not
+    one a point, and that program compiles once."""
+    from repro.core import simulator
+    tr = random_uniform(4, 12, burst=8, seed=2)
+    kw = {} if stages is None else {"stages": stages}
+    knobs = [{}, dict(outstanding=6, cmd_latency=3, reg_rate=64, reg_burst=4),
+             dict(split_buffer=32, ret_latency=2, bank_occupancy=1,
+                  bank_latency=5, qos_aging=16, hop_latency=2,
+                  slice_ingress=8)]
+    simulator._core_jitted_cached.cache_clear()
+    fns = set()
+    for k in knobs:
+        prm = SimParams(max_cycles=300, **kw, **k)
+        if lanes == "single":
+            assert simulate(tr, prm)["all_done"]
+            fns.add(simulator._core_jitted_cached(
+                simulator._static_prm(prm), "single", False))
+            continue
+        pts = [prm, replace(prm, cmd_latency=prm.cmd_latency + 4),
+               replace(prm, qos_aging=2)]
+        pb = prepare_batch([tr] if lanes == "shared" else [tr] * len(pts),
+                           pts, chunk=2 if lanes == "chunked" else None)
+        assert np.asarray(pb.run()["all_done"]).all()
+        fns.add(pb.fn)
+    info = simulator._core_jitted_cached.cache_info()
+    assert (info.currsize, info.misses) == (1, 1)
+    (fn,) = fns
+    assert fn._cache_size() == 1
 
 
 def test_stream_chunked_batch_drains():

@@ -75,7 +75,7 @@ def test_schedule_core_compiles_with_pallas_arbiter(one_chip, monkeypatch):
     sched = urban_perception(txns=256).compile().schedule()
     prm = simulator._static_prm(SimParams(
         max_cycles=20_000, stages=SCHEDULE_PIPELINE, arbiter="pallas"))
-    host = simulator._host_args(sched, prm, True) + (prm.dyn_vector(),)
+    host = simulator._host_input(sched, prm) + (prm.dyn_vector(),)
     args = [jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype,
                                  sharding=one_chip) for a in host]
     core = jax.jit(partial(simulator._core_sched, prm=prm))
@@ -95,8 +95,8 @@ def fig4_dense_text(one_chip):
         burst=np.full((X, N), 16), addr=rng.integers(0, 1 << 19, (X, N)),
         prio=np.zeros(X, int))
     prm = simulator._static_prm(SimParams(max_cycles=22_800))
-    dev = simulator._device_args(prm, *simulator._host_args(trace, prm, False),
-                                 prm.dyn_vector())
+    dev = simulator._device_input(prm, simulator._host_input(trace, prm),
+                                  prm.dyn_vector())
     args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
             for a in dev]
     core = jax.jit(partial(simulator._core, prm=prm))
@@ -124,7 +124,7 @@ def sched_core(one_chip):
     sched = urban_perception(txns=256).compile().schedule()
     prm = simulator._static_prm(SimParams(max_cycles=20_000,
                                           stages=SCHEDULE_PIPELINE))
-    host = simulator._host_args(sched, prm, True) + (prm.dyn_vector(),)
+    host = simulator._host_input(sched, prm) + (prm.dyn_vector(),)
     args = [jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype,
                                  sharding=one_chip) for a in host]
     core_fn = jax.jit(partial(simulator._core_sched, prm=prm))
@@ -196,15 +196,15 @@ def test_vmapped_bank_arbitrate_fuses_its_masks_for_v5e(one_chip):
     trace = simulator.Trace(is_write=rng.integers(0, 2, (X, N)),
                             burst=np.full((X, N), 16),
                             addr=rng.integers(0, 1 << 19, (X, N)))
-    dev = simulator._device_args(prm, *simulator._host_args(trace, prm, False),
-                                 prm.dyn_vector())
+    dev = simulator._device_input(prm, simulator._host_input(trace, prm),
+                                  prm.dyn_vector())
 
     def one_point(st, *point):
-        _, ctx = simulator._dense_setup(*point, prm)
+        _, ctx = simulator._setup(point, prm)
         return simulator._stage_bank_arbitrate(st, {}, ctx)
 
     state = jax.eval_shape(
-        lambda *p: simulator._dense_setup(*p, prm)[0], *dev)
+        lambda *p: simulator._setup(p, prm)[0], *dev)
     batched = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
         (B,) + a.shape, a.dtype, sharding=one_chip)
     args = [jax.tree_util.tree_map(batched, state)] + [batched(a) for a in dev]
